@@ -265,6 +265,21 @@ def _spec_of(leaf):
     return out
 
 
+def dtype_str(dtype) -> str:
+    """A dtype as the manifest spells it: numpy's own string, or the name
+    of an ml_dtypes type (bfloat16, float8_*), whose numpy string is an
+    opaque void that would restore as raw bytes."""
+    dtype = np.dtype(dtype)
+    return dtype.name if dtype.kind == "V" else dtype.str
+
+
+def parse_dtype(spelled: str) -> np.dtype:
+    """Inverse of dtype_str."""
+    import ml_dtypes  # noqa: F401 - registers bfloat16 & co. by name
+
+    return np.dtype(spelled)
+
+
 def flatten_tree(tree) -> list[dict]:
     """Pytree -> ordered leaf records {path, dtype, shape, spec, leaf}.
 
@@ -279,7 +294,7 @@ def flatten_tree(tree) -> list[dict]:
         arr = np.asarray(leaf) if np.isscalar(leaf) else leaf
         records.append({
             "path": _path_entries(path),
-            "dtype": np.dtype(arr.dtype).str,
+            "dtype": dtype_str(arr.dtype),
             "shape": [int(d) for d in arr.shape],
             "spec": _spec_of(leaf),
             "leaf": leaf,
@@ -351,7 +366,7 @@ def build_manifest(
     save_id travels between hosts before the chunks themselves."""
     arrays, offset = [], 0
     for r in records:
-        nbytes = int(np.dtype(r["dtype"]).itemsize * int(np.prod(r["shape"], dtype=np.int64)))
+        nbytes = int(parse_dtype(r["dtype"]).itemsize * int(np.prod(r["shape"], dtype=np.int64)))
         arrays.append({
             "path": r["path"],
             "dtype": r["dtype"],

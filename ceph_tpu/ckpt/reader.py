@@ -195,7 +195,7 @@ class CkptReader:
         records = []
         for a in manifest["arrays"]:
             arr = np.frombuffer(
-                stream, dtype=np.dtype(a["dtype"]),
+                stream, dtype=layout.parse_dtype(a["dtype"]),
                 count=int(np.prod(a["shape"], dtype=np.int64)),
                 offset=a["offset"],
             ).reshape(a["shape"]).copy()
@@ -261,7 +261,7 @@ class CkptReader:
 
         window = window if window is not None else self._window()
         cache = cache if cache is not None else {}
-        dtype = np.dtype(a["dtype"])
+        dtype = layout.parse_dtype(a["dtype"])
         runs = slice_byte_runs(a["shape"], dtype.itemsize, idx)
         if self.perf is not None:
             # host-resident bytes this slab materializes: the counter

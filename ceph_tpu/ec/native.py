@@ -169,9 +169,7 @@ class ErasureCodeNative(ErasureCode):
             built = build_plugin("native", directory=self._directory)
         except RuntimeError as e:  # compile failed: surface the diagnostics
             raise ErasureCodeError(errno.EIO, str(e)) from None
-        if built is None and not os.path.exists(
-            plugin_path("native", self._directory)
-        ):
+        if built is None:
             raise ErasureCodeError(
                 errno.EIO, "no toolchain to build libec_native.so"
             )

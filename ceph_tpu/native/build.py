@@ -3,13 +3,15 @@
 The reference ships its native codecs as autotools/cmake targets producing
 libec_*.so under <libdir>/erasure-code (loaded by ErasureCodePluginRegistry
 at runtime); here a single g++ invocation produces the same artifact shape
-next to the sources, rebuilt only when the source is newer (the pattern the
-test oracle shim uses, tests/c_oracle). No compiler -> None, and callers
-surface the reference's dlopen error path.
+next to the sources, rebuilt unless a sidecar stamp shows it was built from
+this very source and these flags. No compiler -> None, and callers surface
+the reference's dlopen error path; a prebuilt library is never loaded in
+place of a build.
 """
 
 from __future__ import annotations
 
+import hashlib
 import os
 import shutil
 import subprocess
@@ -27,23 +29,49 @@ def plugin_path(name: str, directory: str | None = None) -> str:
     )
 
 
+def _stamp(source: str, cmd: list[str]) -> str:
+    """sha256 of the source and the compile flags: what a library must
+    have been built from to be reused."""
+    h = hashlib.sha256()
+    with open(source, "rb") as f:
+        h.update(f.read())
+    h.update("\0".join(cmd).encode())
+    return h.hexdigest()
+
+
+def _compile(out: str, source: str, cmd: list[str]) -> None:
+    """Build `out` from `source` with `cmd` (flags, no -o) unless its
+    sidecar stamp says it already was. A library copied in from elsewhere,
+    or built from an older source, has no matching stamp and is rebuilt,
+    never loaded. Writes go through temporaries, so concurrent builders
+    never see a half-written library. Raises CalledProcessError when the
+    compiler fails."""
+    stamp = _stamp(source, cmd)
+    try:
+        with open(out + ".sha256") as f:
+            if f.read() == stamp and os.path.exists(out):
+                return
+    except FileNotFoundError:
+        pass
+    tmp = f"{out}.{os.getpid()}.tmp"
+    subprocess.run(
+        [*cmd, "-o", tmp, source], check=True, capture_output=True, text=True
+    )
+    os.replace(tmp, out)
+    with open(tmp, "w") as f:
+        f.write(stamp)
+    os.replace(tmp, out + ".sha256")
+
+
 def build_shared(name: str, source: str) -> str | None:
     """Compile a standalone helper .so (crc32c etc.); returns the path or
-    None without a toolchain. Same rebuild-on-mtime rule as plugins."""
+    None without a toolchain or when the compile fails."""
     cc = shutil.which("cc") or shutil.which("gcc") or shutil.which("g++")
     if cc is None:
         return None
     out = os.path.join(NATIVE_DIR, f"lib{name}.so")
-    if (
-        os.path.exists(out)
-        and os.path.getmtime(out) >= os.path.getmtime(source)
-    ):
-        return out
     try:
-        subprocess.run(
-            [cc, "-O3", "-shared", "-fPIC", "-o", out, source],
-            check=True, capture_output=True, text=True,
-        )
+        _compile(out, source, [cc, "-O3", "-shared", "-fPIC"])
     except subprocess.CalledProcessError:
         return None
     return out
@@ -55,29 +83,21 @@ def build_plugin(
     directory: str | None = None,
 ) -> str | None:
     """Compile `source` into libec_<name>.so; returns the path or None when
-    no toolchain is available. Rebuilds only when the source is newer."""
+    no toolchain is available. Rebuilds unless the stamp matches."""
     cxx = shutil.which("g++") or shutil.which("c++")
     if cxx is None:
         return None
     source = source or os.path.join(NATIVE_DIR, "ec_plugin.cpp")
     out = plugin_path(name, directory)
-    if (
-        os.path.exists(out)
-        and os.path.getmtime(out) >= os.path.getmtime(source)
-    ):
-        return out
     from ceph_tpu import __version__
 
     cmd = [
         cxx, "-O3", "-shared", "-fPIC", "-std=c++17",
         f'-DCEPH_TPU_PLUGIN_VERSION="ceph-tpu-{__version__}"',
-        "-o", out, source,
     ]
     try:
-        subprocess.run(cmd, check=True, capture_output=True, text=True)
+        _compile(out, source, cmd)
     except subprocess.CalledProcessError as e:
         # never fall back silently to a stale .so: surface the diagnostics
-        raise RuntimeError(
-            f"building {out} failed:\n{e.stderr}"
-        ) from None
+        raise RuntimeError(f"building {out} failed:\n{e.stderr}") from None
     return out

@@ -59,11 +59,10 @@ DEFAULT_TILE_WORDS = 65536
 
 
 def available() -> bool:
-    """True when the default backend can compile Mosaic kernels."""
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    """True when the default backend can compile Mosaic kernels. A
+    backend that fails to start raises here rather than reading as
+    "no TPU", which would send the codec down the XLA path in silence."""
+    return jax.default_backend() == "tpu"
 
 
 def pack_matrix(bitmat: np.ndarray) -> np.ndarray:
@@ -138,14 +137,22 @@ def gf_matmul_packed(
         raise ValueError(f"words rows {words.shape[0]} != matrix k {k}")
     tile = min(tile_words, max(128, -(-n4 // 128) * 128))
     grid = (pl.cdiv(n4, tile),)
+    # block indices stay int32 under jax_enable_x64 (which CRUSH turns on):
+    # Mosaic refuses an index map that returns a 64-bit literal 0
+    def whole(i):
+        return jnp.int32(0), jnp.int32(0)
+
+    def column(i):
+        return jnp.int32(0), i
+
     return pl.pallas_call(
         _kernel(k, r),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((r32, k32), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((k, tile), lambda i: (0, i), memory_space=pltpu.VMEM),
+            pl.BlockSpec((r32, k32), whole, memory_space=pltpu.VMEM),
+            pl.BlockSpec((k, tile), column, memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((r, tile), lambda i: (0, i), memory_space=pltpu.VMEM),
+        out_specs=pl.BlockSpec((r, tile), column, memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((r, n4), jnp.int32),
         interpret=interpret,
     )(packed_mat, words)

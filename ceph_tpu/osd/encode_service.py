@@ -55,8 +55,9 @@ class EncodeService:
         #: encode_batch span tagged with batch size and whether this
         #: planar shape compiled fresh or reused a cached executable
         self.tracer = tracer
-        #: (kind, k, m, bucket width) planar shapes already launched —
-        #: a first launch at a shape pays the jit compile
+        #: (op, path, k, n, bucket width) planar shapes already launched,
+        #: op "encode" or "decode" — a first launch at a shape pays the
+        #: jit compile, and the paths say which kernel served the batches
         self._seen_shapes: set[tuple] = set()
         #: seconds the first op of a batch waits for company
         self.window = window
@@ -105,6 +106,22 @@ class EncodeService:
             else:
                 self._mesh_cache = None
         return self._mesh_cache
+
+    def device_path(self) -> str:
+        """Which kernel this process's launches take, for the boot log:
+        the mesh at or above mesh_min_bytes on a multi-device host, the
+        Pallas kernel on one TPU, numpy off the chip."""
+        import jax
+
+        devs = jax.devices()
+        single = "pallas" if gp.available() else "numpy"
+        where = f"{len(devs)} x {devs[0].device_kind}"
+        if len(devs) > 1:
+            return (
+                f"mesh for batches >= {self.mesh_min_bytes} B, "
+                f"{single} below ({where})"
+            )
+        return f"{single} ({where})"
 
     # -- encode ---------------------------------------------------------------
 
@@ -173,7 +190,7 @@ class EncodeService:
 
                 padded, width = _bucket_pad(planes)
                 path, bucket = "mesh", padded.shape[-1]
-                self._note_launch(sp, path, k, n, bucket, len(q))
+                self._note_launch(sp, "encode", path, k, n, bucket, len(q))
                 parity = sharding.mesh_encode_planar(
                     codec, padded, mesh
                 )[:, :width]
@@ -184,7 +201,7 @@ class EncodeService:
                 )
                 words, width = _bucket_pad(words)
                 path, bucket = "pallas", words.shape[-1]
-                self._note_launch(sp, path, k, n, bucket, len(q))
+                self._note_launch(sp, "encode", path, k, n, bucket, len(q))
                 parity = np.asarray(
                     codec.encode_words(words)
                 )[:, :width].view(np.uint8)
@@ -194,7 +211,7 @@ class EncodeService:
                 # jit-per-width (tiny batches would otherwise recompile
                 # for every composition)
                 parity_mat = codec._gen[codec.k:]
-                self._note_launch(sp, path, k, n, bucket, len(q))
+                self._note_launch(sp, "encode", path, k, n, bucket, len(q))
                 if getattr(codec, "_xor_ok", False):
                     parity = np.bitwise_xor.reduce(
                         planes, axis=0
@@ -222,13 +239,13 @@ class EncodeService:
                 if not fut.done():
                     fut.set_exception(e)
 
-    def _note_launch(self, sp, path: str, k: int, n: int,
+    def _note_launch(self, sp, op: str, path: str, k: int, n: int,
                      bucket: int, batch: int) -> None:
         """Tag the batch span with the compile-vs-execute split: a
         planar shape's FIRST launch pays the jit compile, later ones
         reuse the cached executable — the difference dominates tail
         latency and must be attributable in a trace."""
-        shape = (path, k, n, bucket)
+        shape = (op, path, k, n, bucket)
         fresh = shape not in self._seen_shapes
         self._seen_shapes.add(shape)
         if sp is not None:
@@ -318,10 +335,13 @@ class EncodeService:
                     )
             planes = np.stack([np.concatenate(r) for r in rows])
             mesh = self._mesh(planes.shape[1])
+            k, n = codec.k, len(targets)
             if mesh is not None:
                 from ceph_tpu.parallel import sharding
 
                 padded, width = _bucket_pad(planes)
+                self._note_launch(
+                    sp, "decode", "mesh", k, n, padded.shape[-1], len(q))
                 rebuilt = sharding.mesh_decode_planar(
                     codec, list(present), list(targets), padded, mesh
                 )[:, :width]
@@ -331,6 +351,8 @@ class EncodeService:
                     [np.concatenate(r).view(np.int32) for r in rows]
                 )
                 words, width = _bucket_pad(words)
+                self._note_launch(
+                    sp, "decode", "pallas", k, n, words.shape[-1], len(q))
                 rebuilt = np.asarray(
                     codec.decode_words(
                         list(present), list(targets), words
@@ -339,6 +361,8 @@ class EncodeService:
             else:
                 from ceph_tpu.ec import matrices
 
+                self._note_launch(
+                    sp, "decode", "numpy", k, n, planes.shape[1], len(q))
                 dm = matrices.decode_matrix(
                     codec._gen, codec.k, list(present), list(targets)
                 )

@@ -133,6 +133,24 @@ def test_flatten_unflatten_round_trip():
     )
 
 
+@pytest.mark.parametrize(
+    "dtype", ["float32", "int64", "uint8", "bfloat16", "float8_e4m3fn"]
+)
+def test_manifest_dtype_spelling_round_trips(dtype):
+    """bfloat16 and the float8 types are ml_dtypes extensions whose numpy
+    string is an opaque void ('<V2'): the manifest must name them, or a
+    restore hands back raw bytes that JAX refuses."""
+    import jax.numpy as jnp
+
+    arr = np.arange(12, dtype=np.float32).astype(jnp.dtype(dtype))
+    (rec,) = layout.flatten_tree({"w": arr})
+    spelled = rec["dtype"]
+    assert "V" not in spelled
+    back = np.frombuffer(arr.tobytes(), dtype=layout.parse_dtype(spelled))
+    assert back.dtype == arr.dtype
+    assert np.array_equal(back.view(np.uint8), arr.view(np.uint8))
+
+
 # -- chunk fingerprints + incremental diff ------------------------------------
 
 

@@ -1,8 +1,8 @@
 """Tests for the fused packed-lane Pallas kernel (ceph_tpu.ops.gf_pallas).
 
 Runs on the CPU mesh via Pallas interpret mode; bit-exactness is asserted
-against the numpy GF(2^8) oracle (ceph_tpu.ops.gf). The real-TPU compile of
-the same kernel is exercised by bench.py on hardware.
+against the numpy GF(2^8) oracle (ceph_tpu.ops.gf). The compile for a v5e
+chip is tests/test_chip_compile.py; the run on one is chip_smoke.py.
 """
 
 import numpy as np

@@ -19,7 +19,6 @@ import pytest
 
 from ceph_tpu.vstart import VStart
 
-CHILD_ENV = {"CEPH_TPU_JAX_PLATFORM": "cpu"}
 REP_POOL = 1
 EC_POOL = 2
 
@@ -71,7 +70,7 @@ async def create_pools(rados):
 
 @pytest.fixture
 def vstart(tmp_path):
-    v = VStart(str(tmp_path), n_mons=3, n_osds=5, env=CHILD_ENV)
+    v = VStart(str(tmp_path), n_mons=3, n_osds=5)
     v.start()
     yield v
     v.stop()

@@ -195,4 +195,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from ceph_tpu.chip import use_compile_cache
+
+    use_compile_cache()
     sys.exit(main())

@@ -60,18 +60,13 @@ DEFAULT_PROFILES: list[tuple[str, dict, int]] = [
 
 
 def plugin_available(plugin: str) -> bool:
-    """The native plugin needs a C++ toolchain (or a prebuilt .so); every
-    other plugin is pure Python."""
+    """The native plugin is built from its tracked source, so it needs a
+    C++ toolchain; every other plugin is pure Python."""
     if plugin != "native":
         return True
     import shutil
 
-    from ceph_tpu.native.build import plugin_path
-
-    return bool(
-        shutil.which("g++") or shutil.which("c++")
-        or os.path.exists(plugin_path("native"))
-    )
+    return bool(shutil.which("g++") or shutil.which("c++"))
 
 
 def profile_dir(base: str, plugin: str, profile: dict, stripe_width: int) -> str:
