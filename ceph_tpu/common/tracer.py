@@ -26,6 +26,9 @@ Pieces:
     root-rate overrides (-1 inherits; recovery reads can run at 100%
     while steady-state IO stays sampled); all observed at runtime like
     debug levels.
+  * `_LoopProbe` — one per running event loop while any tracer on it is
+    enabled: a `loop_lag` root span every 10 ms, from when the firing was
+    due to when it ran (how late the loop runs every callback).
 
 Flight recorder / tail sampling (the Canopy shape, Kaldor et al. 2017):
 with the tracer enabled, EVERY op records spans — head sampling only
@@ -59,12 +62,14 @@ never start traces of their own.
 
 from __future__ import annotations
 
+import asyncio
 import contextvars
 import heapq
 import json
 import os
 import random
 import time
+import weakref
 from collections import deque
 from typing import Any
 
@@ -76,6 +81,13 @@ from ceph_tpu.common.perf_counters import PerfCounters
 _current: "contextvars.ContextVar[SpanContext | None]" = (
     contextvars.ContextVar("ceph_tracer_ctx", default=None)
 )
+
+
+def now() -> float:
+    """The tracer's clock: seconds since the Unix epoch on the host's wall
+    clock (time.time). Every span start, end and event is stamped with it,
+    and so is any time a span site records as a tag."""
+    return time.time()
 
 
 def current_context() -> "SpanContext | None":
@@ -150,7 +162,7 @@ class Span:
         self.parent_id = parent_id
         self.name = name
         self.service = tracer.service
-        self.start = time.time() if start is None else start
+        self.start = now() if start is None else start
         self.end: float | None = None
         self.tags: dict[str, Any] = dict(tags) if tags else {}
         self.events: list[tuple[float, str]] = []
@@ -162,7 +174,7 @@ class Span:
     # -- recording ------------------------------------------------------------
 
     def log(self, event: str) -> None:
-        self.events.append((time.time(), event))
+        self.events.append((now(), event))
 
     def set_tag(self, key: str, value: Any) -> None:
         self.tags[key] = value
@@ -175,13 +187,14 @@ class Span:
 
     @property
     def duration(self) -> float:
-        return (self.end if self.end is not None else time.time()) - self.start
+        return (self.end if self.end is not None else now()) - self.start
 
-    def finish(self) -> None:
-        """Close the span (idempotent): ring + perf histogram + export."""
+    def finish(self, end: float | None = None) -> None:
+        """Close the span (idempotent): ring + perf histogram + export.
+        `end` backdates the close to a time already read."""
         if self.end is not None:
             return
-        self.end = time.time()
+        self.end = now() if end is None else end
         self._tracer._finished(self)
 
     def __enter__(self) -> "Span":
@@ -240,6 +253,77 @@ class Span:
 
 def _jsonable(v):
     return v if isinstance(v, (str, int, float, bool, type(None))) else str(v)
+
+
+#: seconds between two firings of an event loop's lag probe
+LOOP_PROBE_S = 0.010
+
+
+class _LoopProbe:
+    """The lag probe of one running event loop, shared by every tracer on
+    it. It re-arms itself every LOOP_PROBE_S with call_later; each firing
+    records a root span `loop_lag` that starts when the firing was due and
+    ends when it ran, so its duration is how late the loop ran callbacks
+    then (the role of Ceph's heartbeat-map thread timeouts). The span goes
+    through the first registered tracer that is still enabled; when none
+    is, the probe stops."""
+
+    def __init__(self, loop: asyncio.AbstractEventLoop):
+        self._loop = weakref.ref(loop)
+        self.tracers: list[weakref.ref] = []
+        #: when the pending firing is due, on the tracer's clock
+        self.due = 0.0
+        self.handle: asyncio.TimerHandle | None = None
+
+    def serves(self, loop) -> bool:
+        return self.handle is not None and self._loop() is loop
+
+    def add(self, tracer: "Tracer") -> None:
+        if all(r() is not tracer for r in self.tracers):
+            self.tracers.append(weakref.ref(tracer))
+        if self.handle is None:
+            self._arm()
+
+    def drop(self, tracer: "Tracer") -> None:
+        self.tracers = [r for r in self.tracers if r() not in (None, tracer)]
+        if not self.tracers:
+            self._stop()
+
+    def _stop(self) -> None:
+        if self.handle is not None:
+            self.handle.cancel()
+            self.handle = None
+        loop = self._loop()
+        if loop is not None and _probes.get(loop) is self:
+            del _probes[loop]
+
+    def _arm(self) -> None:
+        # the due time is read before call_later reads the loop's clock,
+        # so a firing is never stamped later than it was due; an empty
+        # context keeps the firings out of whatever op armed the probe
+        self.due = now() + LOOP_PROBE_S
+        self.handle = self._loop().call_later(
+            LOOP_PROBE_S, self._fire, context=contextvars.Context()
+        )
+
+    def _fire(self) -> None:
+        ran = now()
+        self.tracers = [r for r in self.tracers if r() is not None]
+        on = [t for r in self.tracers if (t := r()) is not None and t.enabled]
+        if not on:
+            self._stop()
+            return
+        sp = on[0].start("loop_lag", start=min(self.due, ran), tail=False)
+        if sp is not None:
+            sp.finish(end=ran)
+        self._arm()
+
+
+#: event loop -> its lag probe: one per loop, whatever tracers share it,
+#: so the registry lives with the loops rather than with a tracer
+_probes: "weakref.WeakKeyDictionary[asyncio.AbstractEventLoop, _LoopProbe]" = (
+    weakref.WeakKeyDictionary()
+)
 
 
 class Tracer:
@@ -329,11 +413,32 @@ class Tracer:
         #: Prometheus exporter surface span timings as metrics
         self.perf = PerfCounters("tracer")
         self._export_fh = None
+        #: the lag probe of the event loop this tracer last served on
+        self._probe: _LoopProbe | None = None
+        if self._on:
+            self._join_probe()
 
     # -- config observers (cached-flag refresh, the dout-gate idiom) ----------
 
     def _on_enabled(self, _n, v) -> None:
         self._on = bool(v)
+        if self._on:
+            self._join_probe()
+
+    def _join_probe(self) -> None:
+        """Register with the running event loop's lag probe, starting it
+        if this is the loop's first enabled tracer. Outside a running
+        loop there is nothing to probe yet: start() and join() retry."""
+        loop = asyncio._get_running_loop()
+        if loop is None or (
+            self._probe is not None and self._probe.serves(loop)
+        ):
+            return
+        probe = _probes.get(loop)
+        if probe is None:
+            probe = _probes[loop] = _LoopProbe(loop)
+        probe.add(self)
+        self._probe = probe
 
     def _on_rate(self, _n, v) -> None:
         self._rate = float(v)
@@ -386,7 +491,8 @@ class Tracer:
 
     def start(self, name: str, tags: dict | None = None,
               start: float | None = None,
-              op_type: str | None = None) -> Span | None:
+              op_type: str | None = None,
+              tail: bool = True) -> Span | None:
         """Root span: begins a NEW trace. With the tracer on, a span is
         ALWAYS returned (the flight recorder records every op); the
         sample rate only decides the head `sampled` flag, i.e. whether
@@ -394,17 +500,18 @@ class Tracer:
         `tracer_sample_rate_<type>` override when one is set (recovery
         reads at 100% while steady-state IO stays unsampled);
         unknown/unset types inherit the base rate. Roots are
-        tail-eligible: finish() runs the keep/drop predicates. None
-        only when the tracer is disabled."""
+        tail-eligible unless `tail=False`: finish() runs the keep/drop
+        predicates. None only when the tracer is disabled."""
         if not self._on:
             return None
+        self._join_probe()
         rate = self._rate
         if op_type is not None and self._op_rates:
             rate = self._op_rates.get(op_type, rate)
         sampled = self._rng.random() < rate
         trace_id = f"{self._rng.getrandbits(64):016x}"
         return Span(self, name, trace_id, self._new_id(), None, tags,
-                    start, sampled=sampled, tail=True)
+                    start, sampled=sampled, tail=tail)
 
     def child(self, name: str, tags: dict | None = None,
               start: float | None = None) -> Span | None:
@@ -428,6 +535,7 @@ class Tracer:
         server-slow op promotes even when the client never relays."""
         if not self._on:
             return None
+        self._join_probe()
         ctx = SpanContext.decode(wire)
         if ctx is None or not (ctx.sampled or ctx.flight):
             return None
@@ -739,6 +847,9 @@ class Tracer:
         }
 
     def close(self) -> None:
+        if self._probe is not None:
+            self._probe.drop(self)
+            self._probe = None
         if self._export_fh is not None:
             try:
                 self._export_fh.close()

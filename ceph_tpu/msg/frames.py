@@ -268,7 +268,8 @@ class Message:
     ack: int = 0
     #: optional trace context "trace_id:span_id:flags" (the reference
     #: encodes a jaeger trace context into ProtocolV2 message frames the
-    #: same way); empty = op is untraced, zero downstream cost
+    #: same way); empty = op is untraced, zero downstream cost. A frame
+    #: that coalesces several ops carries their contexts comma-separated
     trace: str = ""
     #: envelope flags (FLAG_*); encoded at struct v5, old decoders skip it
     flags: int = 0

@@ -249,13 +249,15 @@ class Connection:
         tracer = self.messenger.tracer
         if tracer is not None and msg.trace:
             # send-side messenger span: queue wait + encode, finished by
-            # _encode_msg_frame when the frame actually hits the stream
-            sp = tracer.join(
-                msg.trace, "msg_send",
-                tags={"type": msg.type, "from": self.messenger.name},
-            )
-            if sp is not None:
-                msg._send_span = sp
+            # _encode_msg_frame when the frame actually hits the stream;
+            # a coalesced frame carries one context per inner op and
+            # gets a span on each op's trace
+            tags = {"type": msg.type, "from": self.messenger.name}
+            sps = [sp for wire in msg.trace.split(",")
+                   if (sp := tracer.join(wire, "msg_send", tags=tags))
+                   is not None]
+            if sps:
+                msg._send_spans = sps
         self.out_seq += 1
         msg.seq = self.out_seq
         if not self.policy.lossy:
@@ -613,13 +615,14 @@ class Connection:
         path the bulk `raw` bytes ride as their own frame segment
         (MESSAGE_SEG) and never pass through an encoder or a join."""
         m = self.messenger
-        sp = getattr(msg, "_send_span", None)
-        if sp is not None:
-            if corked > 1:
-                sp.set_tag("corked", corked)
-            sp.set_tag("stack", self.stack)
-            sp.finish()
-            msg._send_span = None  # lossless replays re-encode; once only
+        sps = getattr(msg, "_send_spans", None)
+        if sps is not None:
+            for sp in sps:
+                if corked > 1:
+                    sp.set_tag("corked", corked)
+                sp.set_tag("stack", self.stack)
+                sp.finish()
+            msg._send_spans = None  # lossless replays re-encode; once only
         if not self.policy.lossy and self._ack_owed > self._ack_sent:
             msg.ack = self._ack_owed
             self._ack_sent = self._ack_owed
@@ -758,19 +761,21 @@ class Connection:
             size = max(1, len(msg.data))
             # receive-side messenger span: throttle wait + handler
             # (fast-dispatch leg); only traced messages pay anything
-            dsp = None
+            dsps = ()
             if m.tracer is not None and msg.trace:
                 tags = {"type": msg.type, "at": m.name, "stack": self.stack}
                 if batched:
                     tags["batched"] = True
-                dsp = m.tracer.join(msg.trace, "msg_dispatch", tags=tags)
+                dsps = [sp for wire in msg.trace.split(",")
+                        if (sp := m.tracer.join(
+                            wire, "msg_dispatch", tags=tags)) is not None]
             await m.dispatch_throttle.get(size)
             try:
                 await _call(m.dispatcher.ms_dispatch, self, msg)
             finally:
                 await m.dispatch_throttle.put(size)
-                if dsp is not None:
-                    dsp.finish()
+                for sp in dsps:
+                    sp.finish()
                 _notify_dispatch()
         elif frame.tag == Tag.ACK:
             self._apply_peer_ack(Decoder(frame.payload).u64())

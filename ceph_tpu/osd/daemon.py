@@ -48,6 +48,7 @@ import json
 import time
 import zlib
 
+from ceph_tpu.common import tracer as tracing
 from ceph_tpu.common.config import Config
 from ceph_tpu.common.crc import ceph_crc32c
 from ceph_tpu.common.kv import KeyValueDB
@@ -92,17 +93,6 @@ _FULL_OK_OPS = {
     "delete", "rmxattr", "omap_rm", "omap_clear", "unwatch",
 }
 
-#: the active trace id for the op currently executing in this task
-#: (zipkin_trace.h role): set when a traced client op starts, read by
-#: _peer_call so every downstream sub-op hop carries the id — async
-#: context propagates through awaits AND create_task, so the EC write
-#: pipeline's spawned tasks inherit it without plumbing
-import contextvars as _contextvars
-
-_trace_ctx: "_contextvars.ContextVar[str | None]" = (
-    _contextvars.ContextVar("ceph_trace_id", default=None)
-)
-
 
 class _StalePartial(Exception):
     """A prepared sub-stripe RMW found its base superseded by a
@@ -124,9 +114,14 @@ class _SubOpCollector:
         self.fut = fut
         self.peer_name = getattr(conn, "peer_name", None)
         self.peer_nonce = getattr(conn, "peer_nonce", 0)
+        #: when a traced inner op acked, on the tracer's clock: the start
+        #: of its subop_ack_wait span
+        self.acked_at = 0.0
 
     def send_message(self, msg) -> None:
         if not self.fut.done():
+            if msg.trace:
+                self.acked_at = tracing.now()
             self.fut.set_result(msg)
 
 
@@ -566,9 +561,6 @@ class OSDService(Dispatcher):
         #: (primary-side); feeds the PG_DAMAGED health check and clears
         #: when a rescrub comes back clean
         self._scrub_incons: dict[tuple, int] = {}
-        #: trace id -> [(unix ts, "osd.N", event)] span events
-        #: (ZTracer::Trace spans at mini scale)
-        self.traces: dict[str, list] = {}
         # dout-style subsystem logging with the always-on recent ring
         # (src/log/Log.cc); dumped via the `log dump` admin command
         from ceph_tpu.common.log import LogRegistry
@@ -722,22 +714,6 @@ class OSDService(Dispatcher):
                 asyncio.create_task(self._op_shard_worker(shard))
             )
         self._note_map(self.osdmap)
-
-    # -- cross-daemon tracing (src/common/zipkin_trace.h role) ----------------
-
-    def _trace(self, trace_id: str | None, event: str) -> None:
-        """Record one span event under a trace id. Each daemon keeps its
-        own span store; `dump_trace` on the admin surface hands the
-        events out and the client stitches the full multi-daemon
-        timeline (wall clock: every daemon shares the host's)."""
-        if not trace_id:
-            return
-        import time as _time
-
-        store = self.traces.setdefault(trace_id, [])
-        store.append((_time.time(), f"osd.{self.id}", event))
-        if len(self.traces) > 256:  # bound the span store
-            self.traces.pop(next(iter(self.traces)))
 
     def statfs(self) -> dict:
         """Store utilization (ObjectStore::statfs): advertised capacity
@@ -998,10 +974,6 @@ class OSDService(Dispatcher):
         payload["tid"] = tid
         payload["reply_to"] = self.id
         payload["_sent_at"] = time.time()
-        trace_id = _trace_ctx.get()
-        if trace_id is not None:
-            payload["trace_id"] = trace_id
-            self._trace(trace_id, f"{msg_type} -> osd.{osd}")
         # fork a child span per sub-op (the per-replica/EC-shard leg):
         # covers send -> peer apply -> ack, and its context rides the
         # Message so the peer's spans hang off it
@@ -1087,11 +1059,14 @@ class OSDService(Dispatcher):
                 for mtype, payload, raw, wire in pend
             ]
             btid = next(self._tids)
+            # the frame carries every inner op's context, so its
+            # messenger legs land on each op's trace
             conn.send_message(
                 Message(type="subop_batch", tid=btid,
                         epoch=self.osdmap.epoch,
                         payload={"tid": btid, "ops": ops},
-                        raw=b"".join(raw for _, _, raw, _ in pend))
+                        raw=b"".join(raw for _, _, raw, _ in pend),
+                        trace=",".join(w for *_, w in pend if w))
             )
             self.perf.inc("subop_batch_tx")
             self.perf.inc("subop_batch_tx_ops", len(pend))
@@ -1100,14 +1075,17 @@ class OSDService(Dispatcher):
             pass  # waiters time out; _sub_op_persist re-targets/retries
 
     def _reply_peer(
-        self, conn, tid: int, payload: dict, raw: bytes = b""
+        self, conn, p: dict, payload: dict, raw: bytes = b""
     ) -> None:
+        """Answer the sub-op `p`. The reply carries the context the
+        sub-op arrived with (the sender's fork span), so its messenger
+        legs land on the op's trace, on both sides."""
         payload = dict(payload)
-        payload["tid"] = tid
+        payload["tid"] = p["tid"]
         conn.send_message(
-            Message(type="sub_reply", tid=tid,
+            Message(type="sub_reply", tid=p["tid"],
                     epoch=self.osdmap.epoch,
-                    payload=payload, raw=raw)
+                    payload=payload, raw=raw, trace=p.get("_trace", ""))
         )
 
     # -- dispatch -------------------------------------------------------------
@@ -1149,7 +1127,7 @@ class OSDService(Dispatcher):
         self.perf.inc("subop_batch_rx")
         loop = asyncio.get_event_loop()
         raw, off = p["_raw"], 0
-        futs = []
+        cols = []
         for op in p.get("ops") or []:
             ip = dict(op["payload"])
             n = int(op.get("raw_len") or 0)
@@ -1160,28 +1138,35 @@ class OSDService(Dispatcher):
             handler = getattr(self, f"_h_{op['type']}", None)
             if handler is None:
                 continue  # sender's per-op timeout retries it
-            fut = loop.create_future()
-            await handler(_SubOpCollector(conn, fut), ip)
-            futs.append(fut)
-        if futs:
+            col = _SubOpCollector(conn, loop.create_future())
+            await handler(col, ip)
+            cols.append(col)
+        if cols:
             self._spawn(
-                self._subop_batch_ack(conn, p.get("tid", 0), futs)
+                self._subop_batch_ack(conn, p.get("tid", 0), cols)
             )
 
-    async def _subop_batch_ack(self, conn, btid: int, futs) -> None:
+    async def _subop_batch_ack(self, conn, btid: int, cols) -> None:
         """One coalesced sub_reply for every inner op that acked within
         the window; each op acks/fails INDEPENDENTLY — a straggler is
         acked on its own when it completes (or the sender's per-op
         deadline retries it) rather than holding the batch hostage.
         The window is shorter than _sub_op_persist's 2.0s per-op
-        timeout so on-time acks always beat the sender's retry."""
-        done, pending = await asyncio.wait(futs, timeout=1.5)
+        timeout so on-time acks always beat the sender's retry.
+
+        A traced inner op's hold, from its own ack to the send of the
+        reply that carries it, is its subop_ack_wait span, under the
+        sender's fork span."""
+        done, pending = await asyncio.wait(
+            [c.fut for c in cols], timeout=1.5
+        )
         for fut in pending:
             fut.add_done_callback(
                 lambda f, c=conn: self._subop_late_ack(c, f)
             )
-        replies, raws = [], []
-        for fut in futs:
+        replies, raws, wires, waits = [], [], [], []
+        for col in cols:
+            fut = col.fut
             if fut not in done or fut.cancelled() or fut.exception():
                 continue
             m = fut.result()
@@ -1192,12 +1177,22 @@ class OSDService(Dispatcher):
             rp["_raw_len"] = len(m.raw)
             replies.append(rp)
             raws.append(m.raw)
+            if m.trace:
+                wires.append(m.trace)
+                sp = self.tracer.join(
+                    m.trace, "subop_ack_wait", start=col.acked_at,
+                    tags={"batch": len(cols)},
+                )
+                if sp is not None:
+                    waits.append(sp)
         if replies:
+            for sp in waits:
+                sp.finish()
             conn.send_message(
                 Message(type="sub_reply", tid=btid,
                         epoch=self.osdmap.epoch,
                         payload={"tid": btid, "replies": replies},
-                        raw=b"".join(raws))
+                        raw=b"".join(raws), trace=",".join(wires))
             )
 
     def _subop_late_ack(self, conn, fut) -> None:
@@ -1303,7 +1298,7 @@ class OSDService(Dispatcher):
                 return
         else:
             self._hb_inject_until = None  # re-armable once cleared
-        self._reply_peer(conn, p["tid"], {"ok": True})
+        self._reply_peer(conn, p, {"ok": True})
 
     # -- map handling + peering -----------------------------------------------
 
@@ -2681,7 +2676,7 @@ class OSDService(Dispatcher):
     async def _h_pg_info(self, conn, p) -> None:
         pg = self._pg_of(p["pgid"])
         self._reply_peer(
-            conn, p["tid"],
+            conn, p,
             {"last_update": pg.last_update, "head": list(pg.head),
              "tail": pg.log_tail},
         )
@@ -2702,7 +2697,7 @@ class OSDService(Dispatcher):
                 ) != head:
                     bridgeable = False
         self._reply_peer(
-            conn, p["tid"],
+            conn, p,
             {"entries": pg.log_entries(frm) if bridgeable else [],
              "bridgeable": bridgeable, "tail": pg.log_tail},
         )
@@ -2710,7 +2705,7 @@ class OSDService(Dispatcher):
     async def _h_pg_inventory(self, conn, p) -> None:
         pg = self._pg_of(p["pgid"])
         self._reply_peer(
-            conn, p["tid"],
+            conn, p,
             {"inventory": pg.latest_objects(), "head": list(pg.head),
              "tail": pg.log_tail},
         )
@@ -2726,7 +2721,7 @@ class OSDService(Dispatcher):
             if tuple(p["head"]) < pg.head:
                 # a stale reign's backfill must never wipe newer state
                 self._reply_peer(
-                    conn, p["tid"], {"ok": False, "stale": True}
+                    conn, p, {"ok": False, "stale": True}
                 )
                 return
             inventory = p["inventory"]
@@ -2739,7 +2734,7 @@ class OSDService(Dispatcher):
                 txn, inventory, tuple(p["head"]), p["tail"]
             )
             self.store.queue_transaction(txn)
-        self._reply_peer(conn, p["tid"], {"ok": True})
+        self._reply_peer(conn, p, {"ok": True})
 
     def _admit_recovery(self, conn, p, fn) -> bool:
         """Recovery-class admission: a sub-op tagged `qos: recovery`
@@ -2786,11 +2781,11 @@ class OSDService(Dispatcher):
             # carry the errno so the scrubbing primary can tell at-rest
             # corruption (EIO -> read_error) from an absent copy
             self._reply_peer(
-                conn, p["tid"], {"ok": False, "error": e.code}
+                conn, p, {"ok": False, "error": e.code}
             )
             return
         if p.get("ver") is not None and attrs.get("ver") != p["ver"]:
-            self._reply_peer(conn, p["tid"], {"ok": False, "stale": True})
+            self._reply_peer(conn, p, {"ok": False, "stale": True})
             return
         if p.get("runs") is not None:
             data = b"".join(
@@ -2803,7 +2798,7 @@ class OSDService(Dispatcher):
                 k.hex(): v.hex() for k, v in omap.items()
             }
         self._reply_peer(
-            conn, p["tid"],
+            conn, p,
             {"ok": True, "attrs": attrs_out}, raw=data,
         )
 
@@ -2841,7 +2836,7 @@ class OSDService(Dispatcher):
         elif e["kind"] == "delete":
             txn.remove(pg.coll, sname)
         self.store.queue_transaction(txn)
-        self._reply_peer(conn, p["tid"], {"ok": True})
+        self._reply_peer(conn, p, {"ok": True})
 
     async def _h_obj_push_batch(self, conn, p) -> None:
         if self._admit_recovery(conn, p, self._h_obj_push_batch):
@@ -2883,7 +2878,7 @@ class OSDService(Dispatcher):
                 txn.remove(pg.coll, sname)
         self.store.queue_transaction(txn)
         self._reply_peer(
-            conn, p["tid"], {"ok": True, "applied": len(p["items"])}
+            conn, p, {"ok": True, "applied": len(p["items"])}
         )
 
     async def _h_rep_write(self, conn, p) -> None:
@@ -2913,19 +2908,14 @@ class OSDService(Dispatcher):
                 pg.append_log(txn, e)
                 self.store.queue_transaction(txn)
                 self.perf.inc("subop_w")
-        self._reply_peer(conn, p["tid"], {"ok": True})
+        self._reply_peer(conn, p, {"ok": True})
 
     async def _h_ec_sub_write(self, conn, p) -> None:
         self._enqueue_subop(p, self._do_ec_sub_write, conn)
 
     async def _do_ec_sub_write(self, conn, p) -> None:
-        self._trace(
-            p.get("trace_id"),
-            f"ec_sub_write apply shard={p.get('shard')}",
-        )
         with self.perf.time("l_subop_apply"):
             await self._do_ec_sub_write_inner(conn, p)
-        self._trace(p.get("trace_id"), "ec_sub_write acked")
 
     async def _do_ec_sub_write_inner(self, conn, p) -> None:
         """ECBackend::handle_sub_write for our shard."""
@@ -2966,7 +2956,7 @@ class OSDService(Dispatcher):
                 with self.perf.time("l_txn"):
                     self.store.queue_transaction(txn)
                 self.perf.inc("subop_w")
-        self._reply_peer(conn, p["tid"], {"ok": True})
+        self._reply_peer(conn, p, {"ok": True})
 
     # -- cache tiering (PrimaryLogPG promote/flush/proxy, .cc:2341/2305) ------
 
@@ -2991,7 +2981,7 @@ class OSDService(Dispatcher):
                 )
             if not state.exists:
                 self._reply_peer(
-                    conn, p["tid"], {"ok": False, "errno": "ENOENT"}
+                    conn, p, {"ok": False, "errno": "ENOENT"}
                 )
                 return
             omap = {}
@@ -3001,7 +2991,7 @@ class OSDService(Dispatcher):
                 except StoreError:
                     omap = {}
             self._reply_peer(
-                conn, p["tid"],
+                conn, p,
                 {"ok": True,
                  "xattrs": {k: v.hex()
                             for k, v in state.xattrs.items()},
@@ -3028,10 +3018,10 @@ class OSDService(Dispatcher):
                     pg, acting, p["name"],
                     self._state_put_ops(p), [p["_raw"]], None,
                 )
-            self._reply_peer(conn, p["tid"], {"ok": True})
+            self._reply_peer(conn, p, {"ok": True})
         except Exception as e:
             self._reply_peer(
-                conn, p["tid"], {"ok": False, "error": str(e)}
+                conn, p, {"ok": False, "error": str(e)}
             )
 
     async def _h_tier_delete(self, conn, p) -> None:
@@ -3045,15 +3035,15 @@ class OSDService(Dispatcher):
                 await self._primary_ops(
                     pg, acting, p["name"], [{"op": "delete"}], [], None,
                 )
-            self._reply_peer(conn, p["tid"], {"ok": True})
+            self._reply_peer(conn, p, {"ok": True})
         except OpError as e:
             ok = e.code == "ENOENT"  # deleting the never-flushed is fine
             self._reply_peer(
-                conn, p["tid"], {"ok": ok, "errno": e.code}
+                conn, p, {"ok": ok, "errno": e.code}
             )
         except Exception as e:
             self._reply_peer(
-                conn, p["tid"], {"ok": False, "error": str(e)}
+                conn, p, {"ok": False, "error": str(e)}
             )
 
     @staticmethod
@@ -3242,6 +3232,7 @@ class OSDService(Dispatcher):
                          "error": str(e)}
             conn.send_message(
                 Message(type="osd_op_reply", tid=p["tid"],
+                        trace=p.get("_trace", ""),
                         epoch=self.osdmap.epoch, payload=reply)
             )
             return True
@@ -3271,6 +3262,7 @@ class OSDService(Dispatcher):
                              "error": f"no such object {name!r}"}
                 conn.send_message(
                     Message(type="osd_op_reply", tid=p["tid"],
+                            trace=p.get("_trace", ""),
                             epoch=self.osdmap.epoch, payload=reply)
                 )
                 return True
@@ -3363,6 +3355,7 @@ class OSDService(Dispatcher):
             conn.send_message(
                 Message(
                     type="osd_op_reply", tid=p["tid"],
+                    trace=p.get("_trace", ""),
                     epoch=self.osdmap.epoch,
                     payload={"tid": p["tid"], "ok": False,
                              "errno": "EBLOCKLISTED",
@@ -3370,7 +3363,6 @@ class OSDService(Dispatcher):
                 )
             )
             return
-        self._trace(p.get("trace_id"), "op_dispatch")
         shard = self._op_shards[
             zlib.crc32(p["name"].encode()) % len(self._op_shards)
         ]
@@ -3471,7 +3463,6 @@ class OSDService(Dispatcher):
     async def _run_client_op(self, conn, p) -> None:
         pool_id = p["pool"]
         name = p["name"]
-        token = _trace_ctx.set(p.get("trace_id"))
         qs = p.pop("_qspan", None)
         if qs is not None:
             qs.finish()
@@ -3488,22 +3479,16 @@ class OSDService(Dispatcher):
             tail=True,
         )
         stoken = None if span is None else self.tracer.use(span)
-        self._trace(
-            p.get("trace_id"),
-            f"op_execute {p.get('op')} {pool_id}/{name}",
-        )
         try:
             with self.op_tracker.track(
                 f"osd_op({p.get('op')} {pool_id}/{name} "
                 f"from {conn.peer_name})", span=span
             ) as tracked, self.perf.time("l_op_total"):
                 await self._do_osd_op(conn, p, pool_id, name, tracked)
-            self._trace(p.get("trace_id"), "op_replied")
         finally:
             if span is not None:
                 span.finish()
                 self.tracer.release(stoken)
-            _trace_ctx.reset(token)
 
     async def _do_osd_op(self, conn, p, pool_id, name, tracked) -> None:
         try:
@@ -3536,6 +3521,7 @@ class OSDService(Dispatcher):
                     conn.send_message(
                         Message(
                             type="osd_op_reply", tid=p["tid"],
+                            trace=p.get("_trace", ""),
                             epoch=self.osdmap.epoch,
                             payload=redirect_reply(
                                 p["tid"], primary, self.osdmap.epoch,
@@ -3548,6 +3534,7 @@ class OSDService(Dispatcher):
                 conn.send_message(
                     Message(
                         type="osd_op_reply", tid=p["tid"],
+                        trace=p.get("_trace", ""),
                         epoch=self.osdmap.epoch,
                         payload={"tid": p["tid"], "ok": False,
                                  "wrong_primary": True,
@@ -3734,8 +3721,12 @@ class OSDService(Dispatcher):
         except Exception as e:
             reply = {"tid": p["tid"], "ok": False, "error": str(e)}
             reply_raw = b""
+        # a reply carries the context its op arrived with (the client's
+        # op_submit span), not osd_op's: osd_op ends as the reply is
+        # queued, and the legs after that belong to the span waiting on it
         conn.send_message(
             Message(type="osd_op_reply", tid=p["tid"],
+                    trace=p.get("_trace", ""),
                     epoch=self.osdmap.epoch,
                     payload=reply, raw=reply_raw)
         )
@@ -4488,7 +4479,6 @@ class OSDService(Dispatcher):
         """Replica-side op-vector application (the sub-op carries the ops,
         the reference carries the compiled transaction — both re-apply
         deterministically; _sub_op_persist guarantees in-order arrival)."""
-        self._trace(p.get("trace_id"), "rep_ops apply")
         pg = self._pg_of(p["pgid"])
         e = p["entry"]
         async with pg.lock:
@@ -4515,7 +4505,7 @@ class OSDService(Dispatcher):
                 pg.append_log(txn, e)
                 self.store.queue_transaction(txn)
                 self.perf.inc("subop_w")
-        self._reply_peer(conn, p["tid"], {"ok": True})
+        self._reply_peer(conn, p, {"ok": True})
 
     def _omap_delta_txn(
         self, txn: Transaction, coll: str, name: str, delta: dict
@@ -4882,7 +4872,7 @@ class OSDService(Dispatcher):
                 "acting": list(p["acting"]),
                 "backfill": list(p.get("backfill") or ()),
             }
-        self._reply_peer(conn, p["tid"], {"ok": True})
+        self._reply_peer(conn, p, {"ok": True})
 
     async def _replica_read_ok(
         self, pg: PG, acting: list[int], primary: int
@@ -4986,6 +4976,7 @@ class OSDService(Dispatcher):
         self.perf.inc("read_balanced")
         conn.send_message(
             Message(type="osd_op_reply", tid=p["tid"],
+                    trace=p.get("_trace", ""),
                     epoch=self.osdmap.epoch,
                     payload=reply, raw=reply_raw)
         )
@@ -5005,6 +4996,7 @@ class OSDService(Dispatcher):
         def _send(payload: dict, raw: bytes = b"") -> None:
             conn.send_message(
                 Message(type="osd_op_reply", tid=p["tid"],
+                        trace=p.get("_trace", ""),
                         epoch=self.osdmap.epoch,
                         payload=payload, raw=raw)
             )
@@ -5117,16 +5109,16 @@ class OSDService(Dispatcher):
             or (shard is not None
                 and (shard >= len(acting) or acting[shard] != reporter))
         ):
-            self._reply_peer(conn, p["tid"], {"ok": False})
+            self._reply_peer(conn, p, {"ok": False})
             return
         entry = pg.latest_objects().get(name)
         if entry is None or entry["kind"] == "delete":
             # deleted since the report: nothing left to heal
-            self._reply_peer(conn, p["tid"], {"ok": True})
+            self._reply_peer(conn, p, {"ok": True})
             return
         got = await self._object_for_push(pg, entry, shard, acting)
         if got is None:
-            self._reply_peer(conn, p["tid"], {"ok": False})
+            self._reply_peer(conn, p, {"ok": False})
             return
         data, attrs = got
         try:
@@ -5140,7 +5132,7 @@ class OSDService(Dispatcher):
                 timeout=5.0, raw=data,
             )
         except (asyncio.TimeoutError, RuntimeError):
-            self._reply_peer(conn, p["tid"], {"ok": False})
+            self._reply_peer(conn, p, {"ok": False})
             return
         self.perf.inc("read_error_repaired")
         if (d := self.dlog.dout(0)) is not None:
@@ -5153,7 +5145,7 @@ class OSDService(Dispatcher):
             f"{pg.coll}/{shard_name(name, shard)} reported by "
             f"osd.{reporter} healed by primary push",
         )
-        self._reply_peer(conn, p["tid"], {"ok": True})
+        self._reply_peer(conn, p, {"ok": True})
 
     async def _primary_call(
         self, pg: PG, acting: list[int], name: str, p: dict
@@ -5339,6 +5331,7 @@ class OSDService(Dispatcher):
             reply = {"tid": p["tid"], "ok": False, "error": str(e)}
         conn.send_message(
             Message(type="osd_op_reply", tid=p["tid"],
+                    trace=p.get("_trace", ""),
                     epoch=self.osdmap.epoch, payload=reply)
         )
 
@@ -5420,12 +5413,6 @@ class OSDService(Dispatcher):
                 result = {"objects": sorted(objects)}
             elif cmd == "log dump":
                 result = {"entries": self.logs.dump_recent()}
-            elif cmd == "dump_trace":
-                result = {
-                    "events": list(
-                        self.traces.get(p.get("trace_id", ""), [])
-                    )
-                }
             elif cmd == "dump_tracing":
                 # drain the completed-span ring (client spans reported
                 # via trace_report included, so one call returns whole

@@ -25,6 +25,7 @@ import asyncio
 
 import numpy as np
 
+from ceph_tpu.common import tracer as tracing
 from ceph_tpu.ops import gf_pallas as gp
 from ceph_tpu.ops.gf import gf_region_matmul
 
@@ -42,6 +43,30 @@ def _bucket_pad(words: np.ndarray) -> tuple[np.ndarray, int]:
     return padded, w
 
 
+def _device_leg(sp, launch):
+    """Run `launch`: the call into the kernel up to its result held in
+    host memory. On a traced batch, tag the span with the seconds of
+    packing before it (pack_s), when it began (device_start, on the
+    tracer's clock) and how long it took (device_s); an untraced batch
+    takes no timestamp."""
+    if sp is None:
+        return launch()
+    t0 = tracing.now()
+    out = launch()
+    t1 = tracing.now()
+    sp.set_tag("pack_s", t0 - sp.start)
+    sp.set_tag("device_start", t0)
+    sp.set_tag("device_s", t1 - t0)
+    return out
+
+
+def _tag_unpacked(sp) -> None:
+    """The seconds from the device leg's end to every caller's result."""
+    if sp is not None and "device_start" in sp.tags:
+        sp.set_tag("unpack_s", tracing.now() - sp.tags["device_start"]
+                   - sp.tags["device_s"])
+
+
 _UNSET = object()
 
 
@@ -50,10 +75,12 @@ class EncodeService:
         self, window: float = 0.002, max_batch: int = 128,
         mesh_min_bytes: int = 8192, tracer=None,
     ):
-        #: optional distributed tracer: traced ops get an encode_wait
-        #: span (enqueue -> result) and each device launch an
-        #: encode_batch span tagged with batch size and whether this
-        #: planar shape compiled fresh or reused a cached executable
+        #: optional distributed tracer: traced ops get an encode_wait or
+        #: decode_wait span (enqueue -> result) and each device launch an
+        #: encode_batch/decode_batch span tagged with batch size, whether
+        #: this planar shape compiled fresh or reused a cached
+        #: executable, the objects' and the launch's planar bytes, and
+        #: the pack, device and unpack legs
         self.tracer = tracer
         #: (op, path, k, n, bucket width) planar shapes already launched,
         #: op "encode" or "decode" — a first launch at a shape pays the
@@ -190,10 +217,11 @@ class EncodeService:
 
                 padded, width = _bucket_pad(planes)
                 path, bucket = "mesh", padded.shape[-1]
-                self._note_launch(sp, "encode", path, k, n, bucket, len(q))
-                parity = sharding.mesh_encode_planar(
+                self._note_launch(sp, "encode", path, k, n, bucket, len(q),
+                                  planes.nbytes, padded.nbytes)
+                parity = _device_leg(sp, lambda: sharding.mesh_encode_planar(
                     codec, padded, mesh
-                )[:, :width]
+                ))[:, :width]
                 self.mesh_launches += 1
             elif gp.available():
                 words = np.stack(
@@ -201,23 +229,26 @@ class EncodeService:
                 )
                 words, width = _bucket_pad(words)
                 path, bucket = "pallas", words.shape[-1]
-                self._note_launch(sp, "encode", path, k, n, bucket, len(q))
-                parity = np.asarray(
+                self._note_launch(sp, "encode", path, k, n, bucket, len(q),
+                                  planes.nbytes, words.nbytes)
+                parity = _device_leg(sp, lambda: np.asarray(
                     codec.encode_words(words)
-                )[:, :width].view(np.uint8)
+                ))[:, :width].view(np.uint8)
                 parity = parity.reshape(codec.m, -1)
             else:
                 # off-device: exact table-driven numpy planar path — no
                 # jit-per-width (tiny batches would otherwise recompile
                 # for every composition)
                 parity_mat = codec._gen[codec.k:]
-                self._note_launch(sp, "encode", path, k, n, bucket, len(q))
+                self._note_launch(sp, "encode", path, k, n, bucket, len(q),
+                                  planes.nbytes, planes.nbytes)
                 if getattr(codec, "_xor_ok", False):
-                    parity = np.bitwise_xor.reduce(
+                    parity = _device_leg(sp, lambda: np.bitwise_xor.reduce(
                         planes, axis=0
-                    )[None]
+                    )[None])
                 else:
-                    parity = gf_region_matmul(parity_mat, planes)
+                    parity = _device_leg(
+                        sp, lambda: gf_region_matmul(parity_mat, planes))
             self.launches += 1
             self.objects += len(q)
             off = 0
@@ -234,17 +265,21 @@ class EncodeService:
                 off += bs
                 if not fut.done():
                     fut.set_result(chunks)
+            _tag_unpacked(sp)
         except Exception as e:
             for _data, _bs, fut in q:
                 if not fut.done():
                     fut.set_exception(e)
 
     def _note_launch(self, sp, op: str, path: str, k: int, n: int,
-                     bucket: int, batch: int) -> None:
+                     bucket: int, batch: int, object_bytes: int,
+                     launch_bytes: int) -> None:
         """Tag the batch span with the compile-vs-execute split: a
         planar shape's FIRST launch pays the jit compile, later ones
         reuse the cached executable — the difference dominates tail
-        latency and must be attributable in a trace."""
+        latency and must be attributable in a trace. object_bytes are
+        the batch's planar bytes over all input rows, launch_bytes the
+        same rows padded to the bucket: the launch's padded share."""
         shape = (op, path, k, n, bucket)
         fresh = shape not in self._seen_shapes
         self._seen_shapes.add(shape)
@@ -253,6 +288,8 @@ class EncodeService:
             sp.set_tag("width", bucket)
             sp.set_tag("compile", fresh)
             sp.set_tag("batch", batch)
+            sp.set_tag("object_bytes", object_bytes)
+            sp.set_tag("launch_bytes", launch_bytes)
 
     # -- decode ---------------------------------------------------------------
 
@@ -281,6 +318,11 @@ class EncodeService:
         fut = asyncio.get_event_loop().create_future()
         q = self._dec_q.setdefault(key, [])
         q.append((chunks, blocksize, want, fut))
+        # the survivors' bytes the decode reads, and the chunks it rebuilds
+        sp = None if self.tracer is None else self.tracer.child(
+            "decode_wait",
+            tags={"bytes": blocksize * len(present), "targets": len(targets)},
+        )
         if len(q) >= self.max_batch:
             self._flush_decode(key)
             if not any(k[0] == id(codec) for k in self._dec_q):
@@ -299,7 +341,12 @@ class EncodeService:
                     self.window, self._flush_decode_all, id(codec)
                 )
             )
-        return await fut
+        if sp is None:
+            return await fut
+        try:
+            return await fut
+        finally:
+            sp.finish()
 
     def _flush_decode_all(self, codec_id: int) -> None:
         """Window expiry: drain every signature queued for this codec."""
@@ -341,10 +388,11 @@ class EncodeService:
 
                 padded, width = _bucket_pad(planes)
                 self._note_launch(
-                    sp, "decode", "mesh", k, n, padded.shape[-1], len(q))
-                rebuilt = sharding.mesh_decode_planar(
+                    sp, "decode", "mesh", k, n, padded.shape[-1], len(q),
+                    planes.nbytes, padded.nbytes)
+                rebuilt = _device_leg(sp, lambda: sharding.mesh_decode_planar(
                     codec, list(present), list(targets), padded, mesh
-                )[:, :width]
+                ))[:, :width]
                 self.mesh_launches += 1
             elif gp.available():
                 words = np.stack(
@@ -352,21 +400,24 @@ class EncodeService:
                 )
                 words, width = _bucket_pad(words)
                 self._note_launch(
-                    sp, "decode", "pallas", k, n, words.shape[-1], len(q))
-                rebuilt = np.asarray(
+                    sp, "decode", "pallas", k, n, words.shape[-1], len(q),
+                    planes.nbytes, words.nbytes)
+                rebuilt = _device_leg(sp, lambda: np.asarray(
                     codec.decode_words(
                         list(present), list(targets), words
                     )
-                )[:, :width].view(np.uint8).reshape(len(targets), -1)
+                ))[:, :width].view(np.uint8).reshape(len(targets), -1)
             else:
                 from ceph_tpu.ec import matrices
 
                 self._note_launch(
-                    sp, "decode", "numpy", k, n, planes.shape[1], len(q))
+                    sp, "decode", "numpy", k, n, planes.shape[1], len(q),
+                    planes.nbytes, planes.nbytes)
                 dm = matrices.decode_matrix(
                     codec._gen, codec.k, list(present), list(targets)
                 )
-                rebuilt = gf_region_matmul(dm, planes)
+                rebuilt = _device_leg(
+                    sp, lambda: gf_region_matmul(dm, planes))
             self.launches += 1
             self.objects += len(q)
             off = 0
@@ -381,6 +432,7 @@ class EncodeService:
                 off += bs
                 if not fut.done():
                     fut.set_result(out)
+            _tag_unpacked(sp)
         except Exception as e:
             for _c, _b, _w, fut in q:
                 if not fut.done():

@@ -62,11 +62,6 @@ class Objecter(Dispatcher):
         self._rewatch_tasks: set = set()
         self._keyring = keyring
         self._ticket_task: asyncio.Task | None = None
-        #: cross-daemon tracing (zipkin_trace.h): when set, every op
-        #: carries a fresh trace id and collect_trace() stitches the
-        #: multi-daemon timeline from the daemons' span stores
-        self.trace_all = False
-        self.traces: dict[str, list] = {}
         #: Dapper-style span tracer (common/tracer): samples op_submit
         #: roots per tracer_sample_rate and propagates the context on
         #: Message.trace; finished client spans are reported to the
@@ -271,25 +266,6 @@ class Objecter(Dispatcher):
             raise RadosError(reply.get("error", "admin command failed"))
         return reply.get("result", {})
 
-    async def collect_trace(self, trace_id: str) -> list:
-        """Stitch one traced op's FULL timeline: this client's span
-        events + every up OSD's, merged by wall clock (the role of the
-        zipkin collector UI, flattened to a sorted list of
-        (ts, who, event))."""
-        events = list(self.traces.get(trace_id, []))
-        for osd in range(self.osdmap.max_osd):
-            if self.osdmap.is_down(osd):
-                continue
-            try:
-                rep = await self.osd_admin(
-                    osd, "dump_trace", {"trace_id": trace_id},
-                    timeout=5.0,
-                )
-            except RadosError:
-                continue
-            events.extend(tuple(e) for e in rep.get("events", []))
-        return sorted(events)
-
     # -- targeting ------------------------------------------------------------
 
     def _effective_pool(self, pool_id: int) -> int:
@@ -397,18 +373,6 @@ class Objecter(Dispatcher):
         # must carry the same reqid or the OSD's dup detection can never
         # recognize them and non-idempotent ops would double-apply
         tid = next(self._tids)
-        trace_id = None
-        if self.trace_all:
-            # cross-daemon tracing (zipkin_trace.h role): the id rides
-            # the op and every sub-op hop; daemons record span events
-            # keyed by it, collect_trace() stitches the timeline
-            import time as _time
-            import uuid as _uuid
-
-            trace_id = _uuid.uuid4().hex[:16]
-            self.traces[trace_id] = [(
-                _time.time(), self.name, f"op_submit {op} {name}"
-            )]
         # Dapper-style span (sampled): covers submit -> completion
         # including every retarget/resend; the context rides the wire.
         # Child-first: inside an already-traced task (a ckpt_save /
@@ -428,7 +392,7 @@ class Objecter(Dispatcher):
         try:
             return await self._op_submit_inner(
                 pool_id, name, op, data, deadline, last_error, tid,
-                trace_id, span, wire_ctx, extra, read_policy,
+                span, wire_ctx, extra, read_policy,
             )
         except BaseException as e:
             if span is not None:
@@ -508,7 +472,7 @@ class Objecter(Dispatcher):
 
     async def _op_submit_inner(
         self, pool_id, name, op, data, deadline, last_error, tid,
-        trace_id, span, wire_ctx, extra, read_policy=None,
+        span, wire_ctx, extra, read_policy=None,
     ) -> dict:
         may_balance = self._may_balance(op, extra, read_policy)
         # a redirect/timeout from a balanced target degrades THIS op to
@@ -564,8 +528,6 @@ class Objecter(Dispatcher):
                        "op": op}
             if balanced:
                 payload["balanced"] = True
-            if trace_id is not None:
-                payload["trace_id"] = trace_id
             if extra:
                 payload.update(extra)
             fut = asyncio.get_event_loop().create_future()
@@ -602,13 +564,6 @@ class Objecter(Dispatcher):
             finally:
                 self._waiters.pop(tid, None)
             if reply.get("ok"):
-                if trace_id is not None:
-                    import time as _time
-
-                    self.traces[trace_id].append(
-                        (_time.time(), self.name, "op_reply")
-                    )
-                    reply["trace_id"] = trace_id
                 if span is not None:
                     span.log("op_reply")
                     reply["trace"] = span.trace_id

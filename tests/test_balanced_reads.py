@@ -224,7 +224,7 @@ def test_backfilling_member_redirects_and_kill_mid_read_degrades():
         # instead of a race against a six-object backfill that can
         # drain in milliseconds
         async def park_activate(conn, p):
-            reborn._reply_peer(conn, p["tid"], {"ok": True})
+            reborn._reply_peer(conn, p, {"ok": True})
 
         reborn._h_pg_activate = park_activate
         await wait_until(

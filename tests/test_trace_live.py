@@ -1,14 +1,9 @@
-"""Cross-daemon trace propagation (VERDICT r4 task #10, the
-src/common/zipkin_trace.h role): a traced client op carries its trace id
-through client -> primary -> shard sub-op hops; every daemon records
-span events; `dump_trace` on the admin surface hands them out and the
-client stitches the full multi-daemon timeline.
-
-Plus the Dapper-style span tracer (common/tracer): a sampled client
-write produces ONE trace whose spans cover client -> messenger -> osd
-op-queue -> journal/blockstore with parent links forming a single tree,
-drained via the `dump_tracing` admin command and rendered (critical
-path included) by tools/trace_tool.py.
+"""Cross-daemon tracing with the Dapper-style span tracer
+(common/tracer): a sampled client write produces ONE trace whose spans
+cover client -> messenger -> osd op-queue -> sub-op forks to the shard
+holders -> journal/blockstore -> the reply legs back, with parent links
+forming a single tree, drained via the `dump_tracing` admin command and
+rendered (critical path included) by tools/trace_tool.py.
 """
 
 import asyncio
@@ -227,76 +222,128 @@ def test_vstart_traced_slow_write_renders_critical_path(tmp_path):
     run(main())
 
 
-def test_traced_ec_write_shows_multi_daemon_timeline():
+def ring_spans(rados, cluster, trace_id=None) -> dict:
+    """Every sampled span the client and the OSDs hold, by span id (the
+    client's spans also reach its primary by trace_report)."""
+    out = {}
+    for tr in [rados.objecter.tracer] + [
+        o.tracer for o in cluster.osds.values()
+    ]:
+        for s in list(tr._ring):
+            if trace_id is None or s["trace_id"] == trace_id:
+                out[s["span_id"]] = s
+    return out
+
+
+def test_traced_writes_show_every_daemon_and_reply_leg():
+    """One sampled write to an EC pool and one to a replicated pool, each
+    a single trace: spans from the client and at least three OSDs, the
+    shard applies on OSDs other than the primary under the primary's fork
+    spans, and the messenger's send and dispatch on the reply legs, both
+    the sub-op acks and the op's reply. An unsampled op leaves no span."""
+
     async def main():
-        cluster = Cluster()
+        cluster = Cluster(cfg=traced_cluster_cfg())
         await cluster.start()
         rados = Rados("client.tr", cluster.monmap, config=cluster.cfg)
         await rados.connect()
         await cluster.create_pools(rados)
-        io = rados.io_ctx(EC_POOL)
-        rep = rados.io_ctx(REP_POOL)
-        # warm both pools untraced
-        await io.write_full("warm", b"w" * 1000)
-        await rep.write_full("warm", b"w" * 1000)
-
-        rados.objecter.trace_all = True
         data = np.random.default_rng(5).integers(
             0, 256, 20_000, np.uint8
         ).tobytes()
-        reply = await rados.objecter.op_submit(
-            EC_POOL, "traced-obj", "write", data
+        for pool, obj, fork in ((EC_POOL, "traced-ec", "subop_ec_sub_write"),
+                                (REP_POOL, "traced-rep", "subop_rep_ops")):
+            await rados.io_ctx(pool).write_full(obj, data)
+            root = next(
+                s for s in list(rados.objecter.tracer._ring)
+                if s["name"] == "op_submit"
+                and s["tags"].get("object") == obj
+            )
+            await dispatch_quiesce()  # replies' send spans close after
+            spans = list(ring_spans(
+                rados, cluster, root["trace_id"]).values())
+            assert assert_single_tree(spans)["span_id"] == root["span_id"]
+            primary = f"osd.{rados.objecter._calc_target(pool, obj)}"
+            services = {s["service"] for s in spans}
+            assert "client.tr" in services
+            assert len({v for v in services if v.startswith("osd.")}) >= 3
+            by_id = {s["span_id"]: s for s in spans}
+            forks = {s["span_id"] for s in spans
+                     if s["name"] == fork and s["service"] == primary}
+            assert len(forks) >= 2
+            # the shard applies run on the peers, under the fork spans
+            applied = {s["service"] for s in spans
+                       if s["parent_id"] in forks
+                       and s["name"] == "op_queue"}
+            assert applied and primary not in applied
+            # both reply legs are traced, on both sides
+            legs = {(s["name"], s["tags"].get("type"), s["service"])
+                    for s in spans}
+            assert ("msg_send", "osd_op_reply", primary) in legs
+            assert ("msg_dispatch", "osd_op_reply", "client.tr") in legs
+            acks = [s for s in spans if s["name"] in ("msg_send",
+                                                      "msg_dispatch")
+                    and s["tags"].get("type") == "sub_reply"]
+            assert {s["service"] for s in acks
+                    if s["name"] == "msg_dispatch"} == {primary}
+            assert {s["service"] for s in acks
+                    if s["name"] == "msg_send"} == applied
+            assert all(by_id[s["parent_id"]]["name"] == fork for s in acks)
+
+        # an UNSAMPLED op leaves nothing behind, on any daemon
+        before = set(ring_spans(rados, cluster))
+        cluster.cfg.set("tracer_sample_rate", 0.0)
+        await rados.io_ctx(EC_POOL).write_full("untraced", data)
+        await dispatch_quiesce()
+        assert set(ring_spans(rados, cluster)) == before
+        await rados.shutdown()
+        await cluster.stop()
+
+    run(main())
+
+
+def test_coalesced_subop_acks_wait_under_the_fork_span():
+    """Concurrent EC writes coalesce sub-ops to the same peer into one
+    subop_batch frame; each inner op's hold, from its own ack to the
+    send of the reply that carries it, is a subop_ack_wait span under the
+    sender's fork span, and the coalesced frames' messenger legs land on
+    every inner op's trace."""
+
+    async def main():
+        cluster = Cluster(cfg=traced_cluster_cfg())
+        await cluster.start()
+        rados = Rados("client.co", cluster.monmap, config=cluster.cfg)
+        await rados.connect()
+        await cluster.create_pools(rados)
+        io = rados.io_ctx(EC_POOL)
+        payloads = np.random.default_rng(9).integers(
+            0, 256, (24, 6000), np.uint8
         )
-        rados.objecter.trace_all = False
-        trace_id = reply["trace_id"]
-
-        events = await rados.objecter.collect_trace(trace_id)
-        whos = [w for _ts, w, _e in events]
-        labels = [e for _ts, _w, e in events]
-
-        # the full lifecycle is visible...
-        assert any("op_submit" in e for e in labels)
-        assert any("op_dispatch" in e for e in labels)
-        assert any("op_execute" in e for e in labels)
-        assert any("ec_sub_write ->" in e for e in labels)
-        assert any("ec_sub_write apply" in e for e in labels)
-        assert any("op_replied" in e for e in labels)
-        assert any("op_reply" == e for e in labels)
-
-        # ...across MULTIPLE daemons plus the client
-        daemons = {w for w in whos if w.startswith("osd.")}
-        assert len(daemons) >= 3, daemons  # primary + >=2 shard holders
-        assert "client.tr" in whos
-
-        # timeline ordering: submit first, client reply last
-        assert "op_submit" in events[0][2]
-        assert events[-1][2] == "op_reply"
-        ts = [t for t, _w, _e in events]
-        assert ts == sorted(ts)
-
-        # the shard apply happens on daemons that are NOT the primary
-        primary_daemon = next(
-            w for _t, w, e in events if "op_execute" in e
-        )
-        appliers = {
-            w for _t, w, e in events if "ec_sub_write apply" in e
-        }
-        assert appliers - {primary_daemon}, (primary_daemon, appliers)
-
-        # a replicated write traces its rep_ops hops too
-        rados.objecter.trace_all = True
-        reply = await rados.objecter.op_submit(
-            REP_POOL, "traced-rep", "write", b"r" * 5000
-        )
-        rados.objecter.trace_all = False
-        events = await rados.objecter.collect_trace(reply["trace_id"])
-        labels = [e for _t, _w, e in events]
-        assert any("rep_ops ->" in e for e in labels)
-        assert any("rep_ops apply" == e for e in labels)
-
-        # untraced ops leave no spans behind
-        assert len(rados.objecter.traces) == 2
-
+        await asyncio.gather(*(
+            io.write_full(f"co{i}", payloads[i].tobytes())
+            for i in range(24)
+        ))
+        await dispatch_quiesce()
+        assert sum(o.perf.dump()["subop_batch_tx"]
+                   for o in cluster.osds.values()) >= 1
+        spans = ring_spans(rados, cluster)
+        waits = [s for s in spans.values() if s["name"] == "subop_ack_wait"]
+        assert waits
+        for w in waits:
+            fork = spans[w["parent_id"]]
+            assert fork["name"] == "subop_ec_sub_write"
+            assert fork["trace_id"] == w["trace_id"]
+            assert fork["tags"]["to"] == w["service"]
+            assert w["duration"] >= 0 and w["tags"]["batch"] >= 2
+            # the hold ends before the fork span sees the ack
+            assert w["start"] + w["duration"] <= (
+                fork["start"] + fork["duration"])
+        # a coalesced frame's legs: one span per inner op, on its trace
+        batched = [s for s in spans.values() if s["name"] == "msg_dispatch"
+                   and s["tags"].get("type") == "subop_batch"]
+        assert len({s["trace_id"] for s in batched}) >= 2
+        assert all(spans[s["parent_id"]]["name"] == "subop_ec_sub_write"
+                   for s in batched)
         await rados.shutdown()
         await cluster.stop()
 
