@@ -43,6 +43,7 @@ import random
 import tempfile
 from dataclasses import dataclass, field
 
+from ceph_tpu.common.crc import impl as crc_impl
 from ceph_tpu.common.encoding import Decoder, Encoder, encode_payload
 from ceph_tpu.msg.frames import (
     BANNER,
@@ -881,6 +882,12 @@ class Messenger:
         self.perf.add_histogram(
             "corked_run_len", "frames per write wakeup (power-of-two)"
         )
+        # which crc32c checksums every frame: a library that failed to
+        # build leaves each frame on the numpy loop, ~1000x slower
+        self.perf.add_u64(
+            "crc_hw", "1 when frame crc32c runs on the CPU's CRC32 instruction"
+        )
+        self.perf.set("crc_hw", int(crc_impl() == "sse4.2"))
         # hot-path knobs are read per frame: cache them and track runtime
         # changes via config observers instead of paying the env-aware
         # Config.get on every message

@@ -249,6 +249,7 @@ def daemon_main(kind: str, ident: int, spec_path: str) -> None:
             _install_term_handler(loop, _stop)
             print(f"mon.{ident} up at {spec.mon_addrs[ident]}", flush=True)
         elif kind == "osd":
+            from ceph_tpu.common.crc import impl as crc_impl
             from ceph_tpu.osd.daemon import OSDService
 
             osd = OSDService(
@@ -275,7 +276,8 @@ def daemon_main(kind: str, ident: int, spec_path: str) -> None:
             _install_term_handler(loop, _stop)
             print(
                 f"osd.{ident} up at {osd.messenger.my_addr}, encode "
-                f"path {osd.encode_service.device_path()}",
+                f"path {osd.encode_service.device_path()}, crc32c "
+                f"{crc_impl()}",
                 flush=True,
             )
         elif kind == "mds":
