@@ -27,35 +27,150 @@ their plane digit); each group computes U for intact nodes from coupled data
 recovered in earlier groups, MDS-decodes the erased nodes' U across the plane
 (decode_uncoupled -> the scalar mds code), then maps U back to C.
 
-TPU mapping: the sub-chunk axis is a real tensor axis — chunks are
-(q*t, sub_chunk_no, columns) uint8 arrays, pair transforms are vectorized
-GF(2^8) axpy ops over whole plane slices, and the per-plane MDS decodes of an
-order group are BATCHED into one (group, k+nu, columns) decode_array call on
-the inner codec (the jax bit-plane/Pallas kernels). The plane schedule itself
-(host python) is data-independent given the erasure signature, mirroring how
-the reference drives per-plane jerasure calls.
+TPU mapping: the whole layered decode of a batch is ONE jitted program
+(_layered_planes). Chunks are (nodes, q^t planes, W) int32 words, many
+objects side by side along W; every step is column-wise, so objects of
+different sizes share a launch. Per order group the program computes the
+uncoupled values of every point with one (2,2) pair transform, the lost
+nodes' uncoupled values with one MDS decode over all planes, and the lost
+points' coupled values with one small solve, each a GF(2^8) matmul on the
+fused Pallas kernel (ops/gf_pallas.py) on the TPU and on the XLA
+bit-plane path elsewhere. The erasure signature (which nodes are lost,
+the planes' order groups, each lost point's sources) is data built once
+per signature on the host (plane_plan), so one compiled program serves
+every signature; the order groups run in a lax.fori_loop, each over all
+planes with the commit masked to the group's planes.
 """
 
 from __future__ import annotations
 
 import errno
+import functools
 import itertools
-from typing import Mapping, Sequence
+from typing import Mapping, NamedTuple, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ceph_tpu.ec.interface import (
+    DecodeTableCache,
     ErasureCode,
     ErasureCodeError,
     align_up,
     profile_to_int,
     profile_to_string,
 )
+from ceph_tpu.ec.rs import ErasureCodeRs
 from ceph_tpu.ops import gf
+from ceph_tpu.ops import gf_pallas as gp
 
 
 def _pow_int(a: int, x: int) -> int:
     return a ** x
+
+
+class PlanePlan(NamedTuple):
+    """One erasure signature's schedule (ErasureCodeClay.plane_plan)."""
+
+    #: logical chunk ids the program reads, in the order of its input rows
+    inputs: tuple
+    #: logical chunk ids it rebuilds
+    targets: tuple
+    #: the rows of the program's output (one per lost node) that hold them
+    rows: tuple
+    #: order groups the layered decode runs
+    groups: int
+    #: whether the matrices are packed for the Pallas kernel
+    pallas: bool
+    #: the program's array arguments
+    args: tuple
+
+
+@functools.lru_cache(maxsize=None)
+def _pair_rows(q: int, t: int) -> tuple[np.ndarray, np.ndarray]:
+    """Rows (node * q^t + plane) of the hi and the lo point of every
+    coupled pair: node (x, y) in plane z pairs with node (z_vec[y], y) in
+    the plane whose digit y is x, and the point whose x exceeds its plane
+    digit is hi."""
+    S = q ** t
+    hi, lo = [], []
+    for node in range(q * t):
+        x, y = node % q, node // q
+        stride = q ** (t - 1 - y)
+        for z in range(S):
+            dig = (z // stride) % q
+            if x > dig:
+                hi.append(node * S + z)
+                lo.append((y * q + dig) * S + z + (x - dig) * stride)
+    return np.asarray(hi, dtype=np.int32), np.asarray(lo, dtype=np.int32)
+
+
+#: the Pallas kernel's largest tile in this program: at the kernel's
+#: default, the pair transform's (2 x 2) launch needs more than the 16 MiB
+#: of scoped VMEM a v5e kernel may take
+TILE_WORDS = 16384
+
+
+def _gf_rows(mat, rows, pallas: bool):
+    """GF(2^8) matrix x (n, L) int32 word rows -> (r, L) word rows: the
+    Pallas kernel with `mat` packed, or the XLA bit-plane path with `mat`
+    as its bit-matrix. The kernel's tile is the largest power of two up
+    to TILE_WORDS that divides L, so no block runs past the rows."""
+    if not pallas:
+        return ErasureCodeRs._words_fallback(mat, rows)
+    n4 = rows.shape[1]
+    tile = n4 & -n4
+    tile = min(tile, TILE_WORDS) if tile >= 128 else n4
+    return gp.gf_matmul_packed(mat, rows, tile_words=tile)
+
+
+@functools.partial(jax.jit, static_argnames=("q", "t", "pallas"))
+def _layered_planes(words, reads, lost, survivors, order, levels, n_groups,
+                    mds, src, case, pair, solve, *, q, t, pallas):
+    """The layered decode (decode_layered, .cc:647-712) of a batch: words
+    (k, q^t, W) holds the chunks of `reads`; returns the coupled planes of
+    every lost node, (m, q^t, W), so that one executable per plane width
+    serves encode and every decode whatever it rebuilds. The signature's
+    arrays come from plane_plan."""
+    nodes, S = q * t, q ** t
+    width = words.shape[-1]
+    hi, lo = _pair_rows(q, t)
+    c = jnp.zeros((nodes, S, width), jnp.int32).at[reads].set(words)
+    c = c.reshape(nodes * S, width)
+    lost_rows = (lost[:, None] * S + jnp.arange(S, dtype=lost.dtype)).ravel()
+    lost_order = jnp.tile(order, lost.shape[0])[:, None]
+    n_cases = solve.shape[0] // (32 if pallas else 8)
+
+    def group(i, c):
+        # uncoupled values of every point from the coupled values known so
+        # far; right on this group's planes for every surviving node
+        u = c
+        if hi.size:
+            pairs = _gf_rows(pair, jnp.stack(
+                [c[hi].ravel(), c[lo].ravel()]), pallas)
+            pairs = pairs.reshape(2, hi.size, width)
+            u = u.at[hi].set(pairs[0]).at[lo].set(pairs[1])
+        # the lost nodes' uncoupled values, one MDS decode per plane
+        u = u.reshape(nodes, S, width)
+        u_lost = _gf_rows(
+            mds, u[survivors].reshape(survivors.shape[0], -1), pallas)
+        u = u.at[lost].set(u_lost.reshape(-1, S, width))
+        u = u.reshape(nodes * S, width)
+        # the lost points' coupled values: each is row `case` of the solve
+        # matrix applied to two rows of [C; U]
+        both = jnp.concatenate([c, u])
+        cand = _gf_rows(solve, jnp.stack(
+            [both[src[0]].ravel(), both[src[1]].ravel()]), pallas)
+        cand = cand.reshape(n_cases, -1, width)
+        new = cand[0]
+        for r in range(1, n_cases):
+            new = jnp.where((case == r)[:, None], cand[r], new)
+        keep = lost_order == levels[i]
+        return c.at[lost_rows].set(jnp.where(keep, new, c[lost_rows]))
+
+    c = jax.lax.fori_loop(jnp.zeros_like(n_groups), n_groups, group, c)
+    return c.reshape(nodes, S, width)[lost]
 
 
 class ErasureCodeClay(ErasureCode):
@@ -71,6 +186,9 @@ class ErasureCodeClay(ErasureCode):
         self.mds = None  # scalar MDS over k+nu data / m parity (plane decode)
         self.pft = None  # (2,2) pairwise coupling transform code
         self._G4: np.ndarray | None = None  # (4,2) pair generator
+        #: erasure signature -> PlanePlan, and how many were built
+        self._plans = DecodeTableCache()
+        self.plans_built = 0
 
     # -- profile -------------------------------------------------------------
 
@@ -148,6 +266,21 @@ class ErasureCodeClay(ErasureCode):
             rows: gf.gf_invert_matrix(self._G4[list(rows)])
             for rows in itertools.combinations(range(4), 2)
         }
+        # the device program's two fixed matrices: the pair transform
+        # (U_hi, U_lo) from (C_hi, C_lo), and the five ways a lost point's
+        # C follows from two known values X, Y: a dot (C = U = X); own
+        # partner known (X = the partner's C, Y = own U), hi or lo; both
+        # lost (X = own U, Y = the partner's U), hi or lo
+        inv = self._pair_inv
+        solve = np.array([
+            [1, 0], inv[(1, 2)][0], inv[(0, 3)][1],
+            inv[(2, 3)][0], inv[(2, 3)][1][::-1],
+        ], dtype=np.uint8)
+        self._pair_bits = tuple(
+            gf.matrix_to_bitmatrix(a).astype(np.int8)
+            for a in (self._G4[2:4], solve))
+        self._pair_mats = tuple(gp.pack_matrix(b) for b in self._pair_bits)
+        self._plans.clear()
 
     # -- geometry ------------------------------------------------------------
 
@@ -215,188 +348,169 @@ class ErasureCodeClay(ErasureCode):
                 out.append(lin2(a, c_hi, b, c_lo))
         return out
 
-    def _pair_at(self, x: int, y: int, z: int, digits: np.ndarray):
-        """For node (x,y) in plane z: (node_sw, z_sw, is_hi)."""
-        dig = int(digits[z, y])
-        node_sw = y * self.q + dig
-        z_sw = z + (x - dig) * _pow_int(self.q, self.t - 1 - y)
-        return node_sw, z_sw, x > dig, dig
+    # -- the layered decode as one device program ------------------------------
 
-    # -- layered decode (shared by encode and full-chunk decode) ---------------
-
-    def _decode_layered(self, erased: set[int], C: np.ndarray) -> None:
-        """Recover C[node, z, :] for erased nodes in place.
-
-        C: (q*t, sub_chunk_no, cols) uint8; intact entries filled, erased
-        entries arbitrary. Mirrors decode_layered (ErasureCodeClay.cc:647-712)
-        with the per-plane MDS decodes of each order group batched.
-        """
-        q, t, k, m, nu = self.q, self.t, self.k, self.m, self.nu
-        qt = q * t
+    def plane_bucket(self, width: int) -> int:
+        """The plane width (int32 words per plane row) that a batch of
+        `width` words per plane row is padded to: its whole-chunk width,
+        sub_chunk_no * width, taken up to the power-of-two bucket (>= 256
+        words) of the EncodeService's RS batches. One compiled program
+        then serves every batch whose chunks fall in the bucket; padded
+        columns are zero and decode to zero."""
         S = self.sub_chunk_no
-        erased = set(erased)
-        if not erased:
-            return
+        whole = max(256, 1 << (S * width - 1).bit_length())
+        return -(-whole // S)
+
+    def plane_plan(self, present, targets) -> PlanePlan:
+        """The full-chunk decode that rebuilds logical chunks `targets`
+        from logical chunks `present`, as the arguments of the one device
+        program (_layered_planes): the erasure signature is data, so one
+        compiled program per plane width serves every signature, encode
+        included. More than k chunks may be present; the plan names the
+        k it reads in `inputs`. Encode is the plan whose targets are the
+        parity chunks. Memoized per signature, as the inner codec's
+        decode tables are."""
+        key = (tuple(present), tuple(targets))
+
+        def build():
+            self.plans_built += 1
+            return self._build_plan(*key)
+
+        return self._plans.get_or(key, build)
+
+    def _build_plan(self, present, targets) -> PlanePlan:
+        q, t, k, m, nu = self.q, self.t, self.k, self.m, self.nu
+        nodes, S = q * t, self.sub_chunk_no
+        have = {self._node_of(c) for c in present}
+        erased = {n for n in range(nodes)
+                  if self._chunk_of(n) is not None and n not in have}
         if len(erased) > m:
             raise ErasureCodeError(errno.EIO, "too many erasures")
-        # pad erasures to exactly m with unwanted parity nodes (.cc:658-664)
-        for i in range(k + nu, qt):
+        # pad the erasures to exactly m with parity nodes (.cc:658-664)
+        for n in range(k + nu, nodes):
             if len(erased) >= m:
                 break
-            erased.add(i)
+            erased.add(n)
+        lost = sorted(erased)
+        if not {self._node_of(c) for c in targets} <= erased:
+            raise ErasureCodeError(
+                errno.EINVAL, f"targets {list(targets)} are not all lost")
+        survivors = [n for n in range(nodes) if n not in erased]
+        reads = [n for n in survivors if self._chunk_of(n) is not None]
+        bits, packed = self.mds.decode_bitmatrix(survivors, lost)
         digits = self._plane_digits()
-
-        # order[z] = #erased nodes whose x equals their plane digit (.cc:763)
-        order = np.zeros(S, dtype=np.int64)
-        for node in erased:
+        # a plane's order: how many lost nodes are dots in it (.cc:763);
+        # the order groups run in increasing order (decode_layered)
+        order = np.zeros(S, dtype=np.int32)
+        for n in lost:
+            order += digits[:, n // q] == n % q
+        groups = np.unique(order)
+        levels = np.full(t + 1, -1, dtype=np.int32)
+        levels[: groups.size] = groups
+        # each lost point's coupled value is row `case` of the solve
+        # matrix applied to two rows of [C; U] (see prepare)
+        src = np.zeros((2, m * S), dtype=np.int32)
+        case = np.zeros(m * S, dtype=np.int32)
+        at_u = nodes * S
+        for i, node in enumerate(lost):
             x, y = node % q, node // q
-            order += digits[:, y] == x
+            stride = q ** (t - 1 - y)
+            for z in range(S):
+                p, own = i * S + z, at_u + node * S + z
+                dig = int(digits[z, y])
+                if dig == x:
+                    src[:, p] = own
+                    continue
+                partner = y * q + dig
+                row = partner * S + z + (x - dig) * stride
+                if partner in erased:
+                    case[p] = 3 if x > dig else 4
+                    src[:, p] = (own, at_u + row)
+                else:
+                    case[p] = 1 if x > dig else 2
+                    src[:, p] = (row, own)
+        pallas = gp.available()
+        ints = [np.asarray(a, dtype=np.int32) for a in (
+            reads, lost, survivors, order, levels, groups.size)]
+        args = tuple(jnp.asarray(a) for a in (
+            *ints, packed if pallas else bits, src, case,
+            *(self._pair_mats if pallas else self._pair_bits)))
+        return PlanePlan(
+            inputs=tuple(self._chunk_of(n) for n in reads),
+            targets=tuple(targets),
+            rows=tuple(lost.index(self._node_of(c)) for c in targets),
+            groups=int(groups.size),
+            pallas=pallas, args=args)
 
-        U = np.zeros_like(C)
-        present_nodes = [i for i in range(qt) if i not in erased]
-        targets = sorted(erased)
+    def planes(self, plan: PlanePlan, words) -> jnp.ndarray:
+        """Run `plan` on (k, sub_chunk_no, W) int32 words whose row j holds
+        chunk plan.inputs[j] of every object of a batch, each object's
+        chunk cut into its sub_chunk_no planes and the objects side by
+        side along W. Returns (len(plan.targets), sub_chunk_no, W) in
+        host memory."""
+        out = _layered_planes(
+            jnp.asarray(words, dtype=jnp.int32), *plan.args,
+            q=self.q, t=self.t, pallas=plan.pallas)
+        return np.asarray(out)[list(plan.rows)]
 
-        for iscore in range(int(order.max()) + 1):
-            zs = np.nonzero(order == iscore)[0]
-            if zs.size == 0:
-                continue
-            # phase 1: uncoupled values of intact nodes (decode_erasures,
-            # .cc:714-741) — vectorized over the group's planes
-            for node in present_nodes:
-                x, y = node % q, node // q
-                dig = digits[zs, y]
-                z_sw = zs + (x - dig) * _pow_int(q, t - 1 - y)
-                node_sw = y * q + dig
-                c_xy = C[node, zs]  # (G, cols)
-                c_sw = C[node_sw, z_sw]
-                hi = dig < x
-                dot = dig == x
-                # hi view: (C_hi, C_lo) = (c_xy, c_sw); lo view swapped.
-                # the lo value is computed unconditionally: when the pair is
-                # intact this reproduces the value the reference writes from
-                # the pair's hi-side pass (same C inputs), when erased the
-                # pair's C was recovered in the previous order group
-                u_hi = self._pair_solve({0: c_xy, 1: c_sw}, [2])[0]
-                u_lo = self._pair_solve({0: c_sw, 1: c_xy}, [3])[0]
-                U[node, zs] = np.where(
-                    dot[:, None], c_xy, np.where(hi[:, None], u_hi, u_lo)
-                )
-            # phase 2: batched MDS decode of erased U rows (decode_uncoupled,
-            # .cc:743-761): survivors (G, k+nu, cols) -> (G, m', cols)
-            surv = np.stack([U[n][zs] for n in present_nodes[: k + nu]], axis=1)
-            rebuilt = np.asarray(
-                self.mds.decode_array(present_nodes, targets, surv)
-            )
-            for pos, node in enumerate(targets):
-                U[node, zs] = rebuilt[:, pos]
-            # phase 3: recover coupled values of erased nodes (.cc:686-708),
-            # vectorized over the group's planes
-            erased_mask = np.zeros(qt, dtype=bool)
-            erased_mask[sorted(erased)] = True
-            for node in sorted(erased):
-                x, y = node % q, node // q
-                dig = digits[zs, y]
-                z_sw = zs + (x - dig) * _pow_int(q, t - 1 - y)
-                node_sw = y * q + dig
-                pair_erased = erased_mask[node_sw]
-                dot = dig == x
-                hi = dig < x
-                u_own = U[node, zs]
-                u_sw = U[node_sw, z_sw]
-                c_sw = C[node_sw, z_sw]
-                # type-1: C_xy from intact C_sw + own U (.cc:776-812)
-                t1 = np.where(
-                    hi[:, None],
-                    self._pair_solve({1: c_sw, 2: u_own}, [0])[0],
-                    self._pair_solve({0: c_sw, 3: u_own}, [1])[0],
-                )
-                # both erased: full pair from both U (.cc:814-839); done once
-                # from the hi perspective, which also writes the lo partner
-                both_hi, both_lo = self._pair_solve({2: u_own, 3: u_sw}, [0, 1])
-                val = np.where(
-                    dot[:, None],
-                    u_own,
-                    np.where(
-                        ~pair_erased[:, None],
-                        t1,
-                        np.where(hi[:, None], both_hi, C[node, zs]),
-                    ),
-                )
-                C[node, zs] = val
-                scatter = hi & pair_erased
-                if scatter.any():
-                    C[node_sw[scatter], z_sw[scatter]] = both_lo[scatter]
+    def pack_planes(self, objects) -> tuple[np.ndarray, list[int]]:
+        """The program's input for a batch: objects[j] holds object j's
+        chunks of plan.inputs, buffers of one length per object. Each
+        chunk is cut into its q^t planes and the objects lie side by side
+        along W, padded to plane_bucket. Returns the (k, q^t, W) int32
+        words and each object's width in words per plane."""
+        S = self.sub_chunk_no
+        widths = []
+        for chunks in objects:
+            if len(chunks[0]) % (4 * S):
+                raise ErasureCodeError(
+                    errno.EINVAL, f"chunk size {len(chunks[0])} is not a "
+                    f"multiple of 4 * q^t = {4 * S}")
+            widths.append(len(chunks[0]) // (4 * S))
+        words = np.zeros((len(objects[0]), S, self.plane_bucket(sum(widths))),
+                         dtype=np.int32)
+        off = 0
+        for chunks, w in zip(objects, widths):
+            for i, chunk in enumerate(chunks):
+                words[i, :, off: off + w] = np.frombuffer(
+                    chunk, dtype="<i4").reshape(S, w)
+            off += w
+        return words, widths
 
-    # -- chunk-array assembly --------------------------------------------------
+    @staticmethod
+    def unpack_planes(out: np.ndarray, widths: list[int]) -> list:
+        """Per object, its (rows, chunk) uint8 chunks out of the program's
+        (rows, q^t, W) result for a batch that pack_planes laid out."""
+        objs, off = [], 0
+        for w in widths:
+            objs.append(np.ascontiguousarray(
+                out[:, :, off: off + w]).view(np.uint8).reshape(
+                    out.shape[0], -1))
+            off += w
+        return objs
 
-    def _grid_arrays(self, chunks: Mapping[int, np.ndarray], cols: int):
-        """(q*t, S, cols) C array with virtual nodes zeroed; chunks maps
-        logical chunk id -> (S, cols) uint8."""
-        C = np.zeros((self.q * self.t, self.sub_chunk_no, cols), dtype=np.uint8)
-        for chunk_id, arr in chunks.items():
-            C[self._node_of(chunk_id)] = arr
-        return C
+    def _run_batch(self, plan: PlanePlan, rows) -> np.ndarray:
+        """(batch, k, chunk) uint8 rows of plan.inputs -> (batch,
+        len(plan.targets), chunk): the batch side by side in one launch."""
+        rows = np.ascontiguousarray(rows, dtype=np.uint8)
+        words, widths = self.pack_planes(list(rows))
+        return np.stack(self.unpack_planes(self.planes(plan, words), widths))
 
     def encode_array(self, data) -> np.ndarray:
-        """(batch, k, chunk) -> (batch, m, chunk): parity via decode_layered
-        with the parity nodes erased (encode_chunks, .cc:129-157)."""
-        data = np.asarray(data, dtype=np.uint8)
-        batch, k, chunk = data.shape
-        S = self.sub_chunk_no
-        if chunk % S:
-            raise ErasureCodeError(
-                errno.EINVAL, f"chunk size {chunk} not divisible by q^t={S}"
-            )
-        sc = chunk // S
-        cols = batch * sc
-        # (k, S, batch*sc): plane z of chunk j across the whole batch
-        per_node = {
-            j: np.moveaxis(data[:, j].reshape(batch, S, sc), 0, 1).reshape(S, cols)
-            for j in range(k)
-        }
-        C = self._grid_arrays(per_node, cols)
-        erased = {self._node_of(k + i) for i in range(self.m)}
-        self._decode_layered(erased, C)
-        out = np.empty((batch, self.m, chunk), dtype=np.uint8)
-        for i in range(self.m):
-            node = self._node_of(k + i)
-            out[:, i] = np.moveaxis(
-                C[node].reshape(S, batch, sc), 0, 1
-            ).reshape(batch, chunk)
-        return out
+        """(batch, k, chunk) -> (batch, m, chunk): parity via the layered
+        decode with the parity nodes erased (encode_chunks, .cc:129-157)."""
+        plan = self.plane_plan(
+            range(self.k), range(self.k, self.k + self.m))
+        return self._run_batch(plan, data)
 
     def decode_array(self, present, targets, survivors) -> np.ndarray:
-        """Full-chunk decode: all survivor chunks participate (the layered
-        decode needs every intact node, not just k of them)."""
+        """Full-chunk decode: every survivor node takes part (the layered
+        decode needs all of them), so the plan reads k of `present`."""
+        plan = self.plane_plan(present, targets)
+        pos = {c: i for i, c in enumerate(present)}
         survivors = np.asarray(survivors, dtype=np.uint8)
-        batch, _, chunk = survivors.shape
-        S = self.sub_chunk_no
-        if chunk % S:
-            raise ErasureCodeError(
-                errno.EINVAL, f"chunk size {chunk} not divisible by q^t={S}"
-            )
-        sc = chunk // S
-        cols = batch * sc
-        per_node = {
-            p: np.moveaxis(
-                survivors[:, idx].reshape(batch, S, sc), 0, 1
-            ).reshape(S, cols)
-            for idx, p in enumerate(present)
-        }
-        C = self._grid_arrays(per_node, cols)
-        erased = {
-            self._node_of(i)
-            for i in range(self.k + self.m)
-            if i not in set(present)
-        }
-        self._decode_layered(erased, C)
-        out = np.empty((batch, len(targets), chunk), dtype=np.uint8)
-        for pos, tgt in enumerate(targets):
-            node = self._node_of(tgt)
-            out[:, pos] = np.moveaxis(
-                C[node].reshape(S, batch, sc), 0, 1
-            ).reshape(batch, chunk)
-        return out
+        return self._run_batch(
+            plan, survivors[:, [pos[c] for c in plan.inputs]])
 
     # -- repair (the MSR read-minimal path) ------------------------------------
 

@@ -459,6 +459,7 @@ class OSDService(Dispatcher):
         # wire-path counters (frames out, corked runs, envelope format)
         # surface through the same dump/Prometheus path
         self.perf_collection.add(self.messenger.perf)
+        self.perf_collection.add(self.encode_service.perf)
         for key, desc in (
             ("subop_batch_tx", "coalesced multi-op frames sent to peers"),
             ("subop_batch_tx_ops", "sub-ops that rode a coalesced frame"),

@@ -15,8 +15,12 @@ client ops on the 4 op shards, recovery decodes, scrub rebuilds — rides
 the same launch. `launches`/`objects` counters let tests assert the
 coalescing actually happened (objects >> launches under concurrency).
 
-Codecs without the planar API (clay/lrc/shec compositions) fall back to
-their per-object paths transparently.
+CLAY codecs batch through the same queues with their plane API
+(ErasureCodeClay.plane_plan / planes): each object's chunks are cut into
+their q^t planes and laid side by side along the plane axis, and the whole
+layered decode of the batch is one device program, encode included. Codecs
+with neither API (LRC and SHEC compositions) fall back to their per-object
+paths transparently.
 """
 
 from __future__ import annotations
@@ -26,6 +30,7 @@ import asyncio
 import numpy as np
 
 from ceph_tpu.common import tracer as tracing
+from ceph_tpu.common.perf_counters import PerfCounters
 from ceph_tpu.ops import gf_pallas as gp
 from ceph_tpu.ops.gf import gf_region_matmul
 
@@ -41,6 +46,13 @@ def _bucket_pad(words: np.ndarray) -> tuple[np.ndarray, int]:
     padded = np.zeros((*words.shape[:-1], bucket), dtype=words.dtype)
     padded[..., :w] = words
     return padded, w
+
+
+def _has_planes(codec, blocksize: int) -> bool:
+    """Whether `codec` batches through the plane API at this chunk size:
+    a chunk cut into its sub-chunk planes must hold whole int32 words."""
+    return hasattr(codec, "plane_plan") and (
+        blocksize % (4 * codec.get_sub_chunk_count()) == 0)
 
 
 def _device_leg(sp, launch):
@@ -112,6 +124,13 @@ class EncodeService:
         #: device launches / objects served — the coalescing evidence
         self.launches = 0
         self.objects = 0
+        #: the `perf dump` block: plane-API erasure signatures whose plan
+        #: was built at a launch, and launches that found theirs cached
+        self.perf = PerfCounters("encode_service")
+        self.perf.add_u64_counter(
+            "plane_plans_built", "plane-API signature plans built")
+        self.perf.add_u64_counter(
+            "plane_plans_cached", "plane-API launches whose plan was cached")
 
     def _mesh(self, width_bytes: int):
         """The device mesh for a planar launch of `width_bytes`, or None
@@ -155,7 +174,8 @@ class EncodeService:
     async def encode(self, codec, data: bytes) -> dict[int, bytes]:
         """All k+m chunks for one object, batched across callers."""
         blocksize = codec.get_chunk_size(len(data))
-        if not hasattr(codec, "encode_words") or blocksize % 4:
+        if not _has_planes(codec, blocksize) and (
+                not hasattr(codec, "encode_words") or blocksize % 4):
             self.launches += 1
             self.objects += 1
             return codec.encode(range(codec.get_chunk_count()), data)
@@ -209,6 +229,15 @@ class EncodeService:
                 padded[: len(data)] = np.frombuffer(data, dtype=np.uint8)
                 for i in range(k):
                     rows[i].append(padded[i * bs: (i + 1) * bs])
+            if hasattr(codec, "plane_plan"):
+                plan = self._plan(codec, range(k), range(k, n))
+                parity = self._launch_planes(
+                    sp, codec, plan, [[r[j] for r in rows]
+                                      for j in range(len(q))])
+                self._resolve_encode(q, codec, k, n, rows, lambda j, i, off,
+                                     bs: parity[j][i])
+                _tag_unpacked(sp)
+                return
             planes = np.stack([np.concatenate(r) for r in rows])
             mesh = self._mesh(planes.shape[1])
             path, bucket = "numpy", planes.shape[1]
@@ -249,27 +278,57 @@ class EncodeService:
                 else:
                     parity = _device_leg(
                         sp, lambda: gf_region_matmul(parity_mat, planes))
-            self.launches += 1
-            self.objects += len(q)
-            off = 0
-            for j, (data, bs, fut) in enumerate(q):
-                chunks: dict[int, bytes] = {}
-                for logical in range(k):
-                    chunks[codec.chunk_index(logical)] = (
-                        rows[logical][j].tobytes()
-                    )
-                for logical in range(k, n):
-                    chunks[codec.chunk_index(logical)] = parity[
-                        logical - k, off: off + bs
-                    ].tobytes()
-                off += bs
-                if not fut.done():
-                    fut.set_result(chunks)
+            self._resolve_encode(q, codec, k, n, rows, lambda j, i, off,
+                                 bs: parity[i, off: off + bs])
             _tag_unpacked(sp)
         except Exception as e:
             for _data, _bs, fut in q:
                 if not fut.done():
                     fut.set_exception(e)
+
+    def _resolve_encode(self, q, codec, k, n, rows, parity_of) -> None:
+        """Hand every caller its k + m chunks: parity_of(j, i, off, bs) is
+        parity row i of object j, whose chunks start `off` bytes into the
+        launch's planar rows."""
+        self.launches += 1
+        self.objects += len(q)
+        off = 0
+        for j, (data, bs, fut) in enumerate(q):
+            chunks: dict[int, bytes] = {}
+            for logical in range(k):
+                chunks[codec.chunk_index(logical)] = rows[logical][j].tobytes()
+            for logical in range(k, n):
+                chunks[codec.chunk_index(logical)] = parity_of(
+                    j, logical - k, off, bs).tobytes()
+            off += bs
+            if not fut.done():
+                fut.set_result(chunks)
+
+    def _plan(self, codec, present, targets):
+        """The codec's plan for a plane-API launch, counted in `perf`."""
+        built = codec.plans_built
+        plan = codec.plane_plan(present, targets)
+        self.perf.inc("plane_plans_built" if codec.plans_built != built
+                      else "plane_plans_cached")
+        return plan
+
+    def _launch_planes(self, sp, codec, plan, objects) -> list:
+        """One launch of a plane-API codec's device program over a batch:
+        objects[j] holds object j's chunks of plan.inputs, laid out by
+        codec.pack_planes (padded to the bucket of the batch's whole-chunk
+        width, as _bucket_pad pads an RS batch). Returns, per object, its
+        rebuilt chunks of plan.targets as (len(targets), chunk) uint8."""
+        words, widths = codec.pack_planes(objects)
+        k, S = words.shape[0], words.shape[1]
+        # one executable per bucket serves encode and every decode
+        self._note_launch(sp, "layered", "clay", k, codec.m,
+                          S * words.shape[-1], len(objects),
+                          4 * k * S * sum(widths), words.nbytes)
+        if sp is not None:
+            sp.set_tag("planes", S)
+            sp.set_tag("groups", plan.groups)
+        out = _device_leg(sp, lambda: codec.planes(plan, words))
+        return codec.unpack_planes(out, widths)
 
     def _note_launch(self, sp, op: str, path: str, k: int, n: int,
                      bucket: int, batch: int, object_bytes: int,
@@ -303,13 +362,17 @@ class EncodeService:
         if want <= have:
             return {i: bytes(chunks[i]) for i in want}
         blocksize = len(next(iter(chunks.values())))
-        if not hasattr(codec, "decode_words") or blocksize % 4:
+        planes = _has_planes(codec, blocksize)
+        if not planes and (
+                not hasattr(codec, "decode_words") or blocksize % 4):
             self.launches += 1
             self.objects += 1
             return codec.decode(want, chunks)
+        # a plane-API plan reads every survivor it is offered (it picks
+        # k of them); an RS decode reads the first k
         present = tuple(
             sorted(codec.logical_index(p) for p in have)
-        )[: codec.k]
+        )[: None if planes else codec.k]
         targets = tuple(
             sorted(codec.logical_index(p) for p in want - have)
         )
@@ -321,7 +384,8 @@ class EncodeService:
         # the survivors' bytes the decode reads, and the chunks it rebuilds
         sp = None if self.tracer is None else self.tracer.child(
             "decode_wait",
-            tags={"bytes": blocksize * len(present), "targets": len(targets)},
+            tags={"bytes": blocksize * min(len(present), codec.k),
+                  "targets": len(targets)},
         )
         if len(q) >= self.max_batch:
             self._flush_decode(key)
@@ -373,6 +437,15 @@ class EncodeService:
     def _flush_decode_inner(self, key, q, codec, sp) -> None:
         codec_id, present, targets = key
         try:
+            if hasattr(codec, "plane_plan"):
+                plan = self._plan(codec, present, targets)
+                rebuilt = self._launch_planes(sp, codec, plan, [
+                    [chunks[codec.chunk_index(i)] for i in plan.inputs]
+                    for chunks, _bs, _want, _fut in q])
+                self._resolve_decode(q, codec, targets, lambda j, t, off,
+                                     bs: rebuilt[j][t])
+                _tag_unpacked(sp)
+                return
             rows: list[list[np.ndarray]] = [[] for _ in present]
             for chunks, bs, _want, _fut in q:
                 for i, logical in enumerate(present):
@@ -418,22 +491,27 @@ class EncodeService:
                 )
                 rebuilt = _device_leg(
                     sp, lambda: gf_region_matmul(dm, planes))
-            self.launches += 1
-            self.objects += len(q)
-            off = 0
-            for chunks, bs, want, fut in q:
-                out = {
-                    i: bytes(chunks[i]) for i in want if i in chunks
-                }
-                for t, logical in enumerate(targets):
-                    phys = codec.chunk_index(logical)
-                    if phys in want:
-                        out[phys] = rebuilt[t, off: off + bs].tobytes()
-                off += bs
-                if not fut.done():
-                    fut.set_result(out)
+            self._resolve_decode(q, codec, targets, lambda j, t, off,
+                                 bs: rebuilt[t, off: off + bs])
             _tag_unpacked(sp)
         except Exception as e:
             for _c, _b, _w, fut in q:
                 if not fut.done():
                     fut.set_exception(e)
+
+    def _resolve_decode(self, q, codec, targets, rebuilt_of) -> None:
+        """Hand every caller the chunks it wants: rebuilt_of(j, t, off,
+        bs) is target t of object j, whose chunks start `off` bytes into
+        the launch's planar rows."""
+        self.launches += 1
+        self.objects += len(q)
+        off = 0
+        for j, (chunks, bs, want, fut) in enumerate(q):
+            out = {i: bytes(chunks[i]) for i in want if i in chunks}
+            for t, logical in enumerate(targets):
+                phys = codec.chunk_index(logical)
+                if phys in want:
+                    out[phys] = rebuilt_of(j, t, off, bs).tobytes()
+            off += bs
+            if not fut.done():
+                fut.set_result(out)

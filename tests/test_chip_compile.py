@@ -123,3 +123,39 @@ def test_crush_choose_stage_compiles_for_v5e(one_chip, no_cache_reads, x64):
         vary_r=t.chooseleaf_vary_r, stable=t.chooseleaf_stable, out_slots=3,
     ).compile()
     assert _fits_one_chip(compiled)
+
+
+@pytest.mark.parametrize(
+    "lost,width",
+    [
+        ((8, 9, 10, 11), 8),
+        ((8, 9, 10, 11), 32768),
+        ((6,), 8),
+        # a batch of 16 reads of 4 MiB objects: the widest bucket the
+        # degraded-read cell launches
+        ((6,), 32768),
+    ],
+    ids=["clay-encode-8", "clay-encode-32k", "clay-decode1-8",
+         "clay-decode1-32k"],
+)
+def test_clay_layered_program_compiles_for_v5e(
+        one_chip, no_cache_reads, x64, monkeypatch, lost, width):
+    """CLAY(8,4,11)'s whole layered decode, as one program, at the plane
+    widths (int32 words per plane row) of the smallest and the largest
+    bucket: encode (the parity chunks erased) and a one-chunk rebuild."""
+    from ceph_tpu.ec import clay
+
+    ec = factory("clay", {"k": "8", "m": "4", "d": "11",
+                          "scalar_mds": "isa", "technique": "cauchy"})
+    # the plan's matrices packed for the kernel, as on the chip
+    monkeypatch.setattr(gp, "available", lambda: True)
+    present = [i for i in range(12) if i not in lost][:8]
+    plan = ec.plane_plan(present, lost)
+    specs = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+             for a in plan.args]
+    words = jax.ShapeDtypeStruct((8, 64, width), jnp.int32,
+                                 sharding=one_chip)
+    compiled = clay._layered_planes.lower(
+        words, *specs, q=ec.q, t=ec.t, pallas=True).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _fits_one_chip(compiled)
