@@ -125,6 +125,23 @@ class _SubOpCollector:
             self.fut.set_result(msg)
 
 
+def batched_placement(osdmap, pool_id: int) -> bool:
+    """True when the OSD places a pool with one batched CRUSH mapping per
+    map epoch: the pool's rule is one the batched mapper evaluates, and
+    JAX runs on an accelerator. On the CPU backend each process would pay
+    an XLA compile of the mapper to place a few dozen PGs, so it keeps the
+    scalar walk, one PG at a time."""
+    import jax
+
+    from ceph_tpu.crush import jax_mapper
+
+    if jax.default_backend() == "cpu":
+        return False
+    pool = osdmap.pools[pool_id]
+    ruleno = osdmap.find_rule(pool.crush_rule, pool.type, pool.size)
+    return jax_mapper.supports(osdmap.crush, ruleno)
+
+
 def pg_coll(pool: int, ps: int) -> str:
     return f"pg_{pool}_{ps}"
 
@@ -499,6 +516,10 @@ class OSDService(Dispatcher):
             ("tier_evict", "clean objects evicted from the cache"),
             ("op_in_bytes", "client payload bytes written as primary"),
             ("op_out_bytes", "client payload bytes read as primary"),
+            ("placement_pool_launches",
+             "whole-pool placements from one batched CRUSH mapping"),
+            ("placement_scalar_pgs", "PGs placed one scalar CRUSH walk "
+             "at a time"),
         ):
             self.perf.add_u64_counter(key, desc)
         # sampled by the mgr report tick (and perf dump): ops queued on
@@ -927,18 +948,41 @@ class OSDService(Dispatcher):
     def acting_of(self, pool_id: int, ps: int) -> tuple[list[int], int]:
         """Per-epoch memoized placement: heartbeats and per-op targeting
         would otherwise re-run the scalar CRUSH mapper thousands of times
-        per second for identical answers (the OSDMapMapping cache role)."""
+        per second for identical answers (the OSDMapMapping cache role).
+        A miss fills every PG of the pool from one batched mapping where
+        batched_placement says so, else maps the one PG."""
         m = self.osdmap
         if self._acting_cache_epoch != m.epoch:
             self._acting_cache_epoch = m.epoch
             self._acting_cache = {}
         hit = self._acting_cache.get((pool_id, ps))
         if hit is None:
-            _up, _upp, acting, primary = m.pg_to_up_acting_osds(
-                pool_id, ps
-            )
-            hit = (acting, primary)
-            self._acting_cache[(pool_id, ps)] = hit
+            pool = m.pools.get(pool_id)
+            if pool is None or ps >= pool.pg_num:
+                return [], -1
+            batched = batched_placement(m, pool_id)
+            sp = self.tracer.start("placement_map", tags={
+                "pool": pool_id, "pgs": pool.pg_num if batched else 1,
+                "path": "batched" if batched else "scalar",
+            }, tail=False)
+            try:
+                if batched:
+                    for i, (_up, _upp, acting, primary) in enumerate(
+                        m.pool_up_acting(pool_id)
+                    ):
+                        self._acting_cache[(pool_id, i)] = (acting, primary)
+                    self.perf.inc("placement_pool_launches")
+                    hit = self._acting_cache[(pool_id, ps)]
+                else:
+                    _up, _upp, acting, primary = m.pg_to_up_acting_osds(
+                        pool_id, ps
+                    )
+                    hit = (acting, primary)
+                    self._acting_cache[(pool_id, ps)] = hit
+                    self.perf.inc("placement_scalar_pgs")
+            finally:
+                if sp is not None:
+                    sp.finish()
         return hit
 
     def object_pg(self, pool_id: int, name: str) -> int:

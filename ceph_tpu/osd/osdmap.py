@@ -10,14 +10,17 @@ Re-expresses the reference's `OSDMap` placement path (src/osd/OSDMap.cc):
      -> acting (pg_temp/primary_temp)         OSDMap.cc:2515 _get_temp_osds
                                               OSDMap.cc:2591 _pg_to_up_acting_osds
 
-Two drivers share the exact same semantics:
+Three drivers share the exact same semantics:
 
   * `pg_to_up_acting_osds(pool_id, ps)` — scalar, mirrors the C control flow,
     used by tests and one-off lookups;
   * `pool_mappings(pool_id)` — the whole pool in one batched TPU mapper
     launch (ceph_tpu.crush.jax_mapper.map_rule) plus vectorized numpy
     post-processing: the TPU-native replacement for the reference's
-    thread-pool ParallelPGMapper (OSDMapMapping.h:18).
+    thread-pool ParallelPGMapper (OSDMapMapping.h:18);
+  * `pool_up_acting(pool_id)` — every PG's (up, up_primary, acting,
+    acting_primary) from one batched launch, for the OSD's per-epoch
+    placement cache.
 
 `calc_pg_upmaps` is the balancer step (OSDMap.cc:4512): it computes per-OSD
 PG load from the batched mapping, then greedily moves PGs from the most
@@ -312,8 +315,16 @@ class OSDMap:
         pool = self.pools.get(pool_id)
         if pool is None or ps >= pool.pg_num:
             return [], -1, [], -1
-        acting, acting_primary = self.get_temp_osds(pool_id, ps)
         raw, pps = self.pg_to_raw_osds(pool_id, ps)
+        return self._up_acting_from_raw(pool_id, ps, pps, raw)
+
+    def _up_acting_from_raw(
+        self, pool_id: int, ps: int, pps: int, raw: list[int]
+    ) -> tuple[list[int], int, list[int], int]:
+        """Every step of _pg_to_up_acting_osds after _pg_to_raw_osds:
+        upmap, up, primary, primary affinity and the temp overlay."""
+        pool = self.pools[pool_id]
+        acting, acting_primary = self.get_temp_osds(pool_id, ps)
         raw = self.apply_upmap(pool_id, ps, raw)
         up = self.raw_to_up_osds(pool, raw)
         up_primary = self.pick_primary(up)
@@ -354,6 +365,38 @@ class OSDMap:
         if self._compiled is None:
             self._compiled = jax_mapper.compile_map_cached(self.crush)
         return self._compiled
+
+    def pool_up_acting(
+        self, pool_id: int
+    ) -> list[tuple[list[int], int, list[int], int]]:
+        """pg_to_up_acting_osds for EVERY PG of a pool, the CRUSH walks
+        in one batched mapper launch (the OSDMapMapping::update role).
+
+        Element ps is exactly pg_to_up_acting_osds(pool_id, ps): each
+        row's raw result, cut to the rule's emitted length, goes through
+        the same scalar post-CRUSH steps (nonexistent, upmap, up,
+        primary, primary affinity, pg_temp/primary_temp), which are list
+        and dict work. The pool's rule must be one the batched mapper
+        evaluates (jax_mapper.supports); map_rule refuses any other."""
+        from ceph_tpu.crush import jax_mapper
+
+        pool = self.pools[pool_id]
+        ruleno = self.find_rule(pool.crush_rule, pool.type, pool.size)
+        # one lane per ps up to pg_num's power of two: a pool whose pg_num
+        # grows a step at a time (a split) reuses one executable per octave
+        pps = pool.raw_pg_to_pps_np(
+            pool_id, np.arange(pool.pg_mask + 1, dtype=np.int64)
+        )
+        raw, lengths = jax_mapper.map_rule(
+            self._compile(), ruleno, pps.astype(np.int32), self.osd_weight,
+            pool.size, return_lengths=True,
+        )
+        out = []
+        rows = zip(raw[: pool.pg_num].tolist(), lengths.tolist())
+        for ps, (row, n) in enumerate(rows):
+            row = self._remove_nonexistent(pool, row[:n])
+            out.append(self._up_acting_from_raw(pool_id, ps, int(pps[ps]), row))
+        return out
 
     def pool_mappings(
         self, pool_id: int, runtime_weights=None, return_raw=False

@@ -125,6 +125,38 @@ def test_crush_choose_stage_compiles_for_v5e(one_chip, no_cache_reads, x64):
     assert _fits_one_chip(compiled)
 
 
+def test_crush_indep_stage_compiles_for_v5e(one_chip, no_cache_reads, x64):
+    """The choose indep stage an OSD's whole-pool placement launches for
+    the benchmark's pool: Ceph's failure-domain-osd EC rule (rule 2 as
+    benchmark/cluster.py writes it) over initial_osdmap(12), 11 slots
+    (RS(8,3)), one lane per PG of its 32."""
+    from ceph_tpu.crush import builder as cb
+    from ceph_tpu.crush import jax_mapper as jm
+    from ceph_tpu.crush.types import RuleOp, RuleStep
+    from ceph_tpu.vstart import initial_osdmap
+
+    cmap = initial_osdmap(12).crush
+    cb.make_rule(cmap, 2, [
+        RuleStep(RuleOp.SET_CHOOSELEAF_TRIES, 5),
+        RuleStep(RuleOp.SET_CHOOSE_TRIES, 100),
+        RuleStep(RuleOp.TAKE, -1),
+        RuleStep(RuleOp.CHOOSE_INDEP, 0, 0),
+        RuleStep(RuleOp.EMIT),
+    ], rule_type=3, max_size=11)
+    assert jm.supports(cmap, 2)
+    cm = jm.compile_map(cmap)
+    lanes, slots = 32, 11
+    compiled = jm._choose_indep_b.lower(
+        jax.ShapeDtypeStruct((lanes,), jnp.int32, sharding=one_chip),
+        None,
+        jax.ShapeDtypeStruct((cmap.max_devices,), jnp.int64,
+                             sharding=one_chip),
+        cm=cm, start_bid=-1, numrep=slots, out_slots=slots, want_type=0,
+        recurse_to_leaf=False, tries=100, recurse_tries=5,
+    ).compile()
+    assert _fits_one_chip(compiled)
+
+
 @pytest.mark.parametrize(
     "lost,width",
     [

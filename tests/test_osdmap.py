@@ -13,7 +13,9 @@ import numpy as np
 import pytest
 
 from ceph_tpu.crush import builder as cb
-from ceph_tpu.crush.types import BucketAlg, CrushMap, Tunables
+from ceph_tpu.crush.types import (
+    BucketAlg, CrushMap, RuleOp, RuleStep, Tunables,
+)
 from ceph_tpu.osd import OSDMap, PgPool, ceph_stable_mod, pg_num_mask
 from ceph_tpu.osd.osdmap import CRUSH_ITEM_NONE
 from ceph_tpu.osd.types import TYPE_ERASURE, TYPE_REPLICATED
@@ -37,6 +39,12 @@ def build_cluster(n_hosts=6, per_host=4, seed=7):
     cb.make_bucket(cmap, -1, BucketAlg.STRAW2, 10, host_ids, host_weights)
     cb.make_simple_rule(cmap, 0, -1, 1, "firstn", 0)
     cb.make_simple_rule(cmap, 1, -1, 1, "indep", 0)
+    # an erasure rule that emits 3 slots whatever the pool's size
+    cb.make_rule(cmap, 2, [
+        RuleStep(RuleOp.TAKE, -1),
+        RuleStep(RuleOp.CHOOSELEAF_INDEP, 3, 1),
+        RuleStep(RuleOp.EMIT),
+    ], rule_type=3)
     del rng
     return cmap
 
@@ -259,3 +267,98 @@ def test_batched_matches_scalar_with_primary_affinity():
         want = np.full(3, CRUSH_ITEM_NONE, np.int32)
         want[: len(up)] = up
         assert np.array_equal(batched[ps], want), ps
+
+
+#: the map changes pool_up_acting is held to the scalar pipeline across,
+#: applied in order (each case replays the sequence up to its step)
+PLACEMENT_STEPS = (
+    "boot", "down", "out", "pg_temp", "primary_temp", "pg_upmap_items",
+    "primary_affinity", "split",
+)
+#: pool id -> what it exercises: a replicated pool (firstn, left-compacted
+#: up sets), an erasure pool (indep, positional NONE holes), and an erasure
+#: pool whose rule emits 3 of its 5 slots (the rows' emitted length)
+PLACEMENT_POOLS = {1: "replicated", 2: "erasure", 3: "erasure-short-rule"}
+
+
+def _placement_map():
+    m = build_osdmap()
+    m.pools[3] = PgPool(pg_num=32, size=5, type=TYPE_ERASURE, crush_rule=2)
+    # pg_num 24 (not a power of two) until the split takes it to 32
+    for pool in m.pools.values():
+        pool.pg_num = pool.pgp_num = 24
+    # osd.22 and osd.23 are in the crush map but not yet booted
+    m.osd_exists[22:] = False
+    m.osd_up[22:] = False
+    return m
+
+
+def _first_not_in(m, osds):
+    return next(
+        o for o in range(m.max_osd)
+        if o not in osds and not m.is_down(o) and m.osd_weight[o]
+    )
+
+
+def _apply_placement_step(m, step: str) -> None:
+    if step == "boot":
+        # osd.22 boots; osd.23 stays nonexistent for the rest
+        m.osd_exists[22] = True
+        m.mark_up(22)
+    elif step == "down":
+        m.mark_down(5)
+    elif step == "out":
+        m.mark_out(9)
+    elif step == "pg_temp":
+        for pid, pool in m.pools.items():
+            up = m.pg_to_up_acting_osds(pid, 3)[0]
+            # the down osd.5 leads: compacted away or left as a hole
+            temp = [5]
+            while len(temp) < pool.size:
+                temp.append(_first_not_in(m, up + temp))
+            m.pg_temp[(pid, 3)] = temp
+    elif step == "primary_temp":
+        for pid in m.pools:
+            m.primary_temp[(pid, 3)] = m.pg_temp[(pid, 3)][2]
+            live = [o for o in m.pg_to_up_acting_osds(pid, 4)[0]
+                    if o != CRUSH_ITEM_NONE]
+            m.primary_temp[(pid, 4)] = live[-1]
+    elif step == "pg_upmap_items":
+        for pid in m.pools:
+            up = m.pg_to_up_acting_osds(pid, 7)[0]
+            frm = next(o for o in up if o != CRUSH_ITEM_NONE)
+            m.pg_upmap_items[(pid, 7)] = [(frm, _first_not_in(m, up))]
+        up = m.pg_to_up_acting_osds(1, 8)[0]
+        m.pg_upmap[(1, 8)] = [_first_not_in(m, up), up[0], up[1]]
+    elif step == "primary_affinity":
+        m.osd_primary_affinity = np.full(m.max_osd, 0x10000, np.int64)
+        m.osd_primary_affinity[0] = 0
+        m.osd_primary_affinity[1] = 0x8000
+        m.osd_primary_affinity[12] = 0x4000
+        m.epoch += 1
+    elif step == "split":
+        # pg_num grows ahead of pgp_num, as a split begins
+        for pool in m.pools.values():
+            pool.pg_num = 32
+        m.epoch += 1
+
+
+@pytest.mark.parametrize("step", PLACEMENT_STEPS)
+@pytest.mark.parametrize(
+    "pool_id", list(PLACEMENT_POOLS), ids=list(PLACEMENT_POOLS.values())
+)
+def test_pool_up_acting_matches_scalar_across_map_changes(pool_id, step):
+    """The whole-pool placement an OSD fills its per-epoch cache from is
+    the scalar pipeline's answer for every PG, after every kind of map
+    change, from the batched mapper."""
+    from ceph_tpu.crush import jax_mapper
+
+    m = _placement_map()
+    for s in PLACEMENT_STEPS[: PLACEMENT_STEPS.index(step) + 1]:
+        _apply_placement_step(m, s)
+    pool = m.pools[pool_id]
+    ruleno = m.find_rule(pool.crush_rule, pool.type, pool.size)
+    assert jax_mapper.supports(m.crush, ruleno)
+    want = [m.pg_to_up_acting_osds(pool_id, ps)
+            for ps in range(pool.pg_num)]
+    assert m.pool_up_acting(pool_id) == want
