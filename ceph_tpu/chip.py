@@ -1,7 +1,7 @@
 """Start-up of the entry points that hold the chip.
 
 A chip belongs to one process. The entry points that take it
-(chip_smoke.py, bench.py, tools/crush_bench.py, tools/ec_bench.py) call
+(chip_smoke.py, tools/crush_bench.py, tools/ec_bench.py) call
 use_compile_cache() before their first JAX computation, so a second run
 on the same machine reads its executables back instead of compiling from
 cold. Tests never call it: a compile for a described chip

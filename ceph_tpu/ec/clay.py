@@ -47,7 +47,7 @@ from __future__ import annotations
 import errno
 import functools
 import itertools
-from typing import Mapping, NamedTuple, Sequence
+from typing import Mapping, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -57,34 +57,18 @@ from ceph_tpu.ec.interface import (
     DecodeTableCache,
     ErasureCode,
     ErasureCodeError,
+    PlanePlan,
     align_up,
     profile_to_int,
     profile_to_string,
 )
-from ceph_tpu.ec.rs import ErasureCodeRs
 from ceph_tpu.ops import gf
+from ceph_tpu.ops import gf_bitplane as bp
 from ceph_tpu.ops import gf_pallas as gp
 
 
 def _pow_int(a: int, x: int) -> int:
     return a ** x
-
-
-class PlanePlan(NamedTuple):
-    """One erasure signature's schedule (ErasureCodeClay.plane_plan)."""
-
-    #: logical chunk ids the program reads, in the order of its input rows
-    inputs: tuple
-    #: logical chunk ids it rebuilds
-    targets: tuple
-    #: the rows of the program's output (one per lost node) that hold them
-    rows: tuple
-    #: order groups the layered decode runs
-    groups: int
-    #: whether the matrices are packed for the Pallas kernel
-    pallas: bool
-    #: the program's array arguments
-    args: tuple
 
 
 @functools.lru_cache(maxsize=None)
@@ -118,7 +102,7 @@ def _gf_rows(mat, rows, pallas: bool):
     as its bit-matrix. The kernel's tile is the largest power of two up
     to TILE_WORDS that divides L, so no block runs past the rows."""
     if not pallas:
-        return ErasureCodeRs._words_fallback(mat, rows)
+        return bp.gf_matmul_words(mat, rows)
     n4 = rows.shape[1]
     tile = n4 & -n4
     tile = min(tile, TILE_WORDS) if tile >= 128 else n4
@@ -350,17 +334,6 @@ class ErasureCodeClay(ErasureCode):
 
     # -- the layered decode as one device program ------------------------------
 
-    def plane_bucket(self, width: int) -> int:
-        """The plane width (int32 words per plane row) that a batch of
-        `width` words per plane row is padded to: its whole-chunk width,
-        sub_chunk_no * width, taken up to the power-of-two bucket (>= 256
-        words) of the EncodeService's RS batches. One compiled program
-        then serves every batch whose chunks fall in the bucket; padded
-        columns are zero and decode to zero."""
-        S = self.sub_chunk_no
-        whole = max(256, 1 << (S * width - 1).bit_length())
-        return -(-whole // S)
-
     def plane_plan(self, present, targets) -> PlanePlan:
         """The full-chunk decode that rebuilds logical chunks `targets`
         from logical chunks `present`, as the arguments of the one device
@@ -440,7 +413,9 @@ class ErasureCodeClay(ErasureCode):
             targets=tuple(targets),
             rows=tuple(lost.index(self._node_of(c)) for c in targets),
             groups=int(groups.size),
-            pallas=pallas, args=args)
+            pallas=pallas, path="clay",
+            # one executable per bucket serves encode and every decode
+            program=("layered", m), args=args)
 
     def planes(self, plan: PlanePlan, words) -> jnp.ndarray:
         """Run `plan` on (k, sub_chunk_no, W) int32 words whose row j holds
@@ -452,42 +427,6 @@ class ErasureCodeClay(ErasureCode):
             jnp.asarray(words, dtype=jnp.int32), *plan.args,
             q=self.q, t=self.t, pallas=plan.pallas)
         return np.asarray(out)[list(plan.rows)]
-
-    def pack_planes(self, objects) -> tuple[np.ndarray, list[int]]:
-        """The program's input for a batch: objects[j] holds object j's
-        chunks of plan.inputs, buffers of one length per object. Each
-        chunk is cut into its q^t planes and the objects lie side by side
-        along W, padded to plane_bucket. Returns the (k, q^t, W) int32
-        words and each object's width in words per plane."""
-        S = self.sub_chunk_no
-        widths = []
-        for chunks in objects:
-            if len(chunks[0]) % (4 * S):
-                raise ErasureCodeError(
-                    errno.EINVAL, f"chunk size {len(chunks[0])} is not a "
-                    f"multiple of 4 * q^t = {4 * S}")
-            widths.append(len(chunks[0]) // (4 * S))
-        words = np.zeros((len(objects[0]), S, self.plane_bucket(sum(widths))),
-                         dtype=np.int32)
-        off = 0
-        for chunks, w in zip(objects, widths):
-            for i, chunk in enumerate(chunks):
-                words[i, :, off: off + w] = np.frombuffer(
-                    chunk, dtype="<i4").reshape(S, w)
-            off += w
-        return words, widths
-
-    @staticmethod
-    def unpack_planes(out: np.ndarray, widths: list[int]) -> list:
-        """Per object, its (rows, chunk) uint8 chunks out of the program's
-        (rows, q^t, W) result for a batch that pack_planes laid out."""
-        objs, off = [], 0
-        for w in widths:
-            objs.append(np.ascontiguousarray(
-                out[:, :, off: off + w]).view(np.uint8).reshape(
-                    out.shape[0], -1))
-            off += w
-        return objs
 
     def _run_batch(self, plan: PlanePlan, rows) -> np.ndarray:
         """(batch, k, chunk) uint8 rows of plan.inputs -> (batch,

@@ -21,14 +21,16 @@ base-class logic of `ceph::ErasureCode`
 The byte-level encode/decode API mirrors the reference for drop-in test parity;
 the TPU-native entry points are the batched array methods (`encode_array` /
 `decode_array`) that concrete codecs implement over (batch, k, chunk) uint8
-tensors — that is where stripes from many objects get packed into one launch.
+tensors, and the plane API (`plane_plan` / `planes`, over the shared
+`pack_planes` layout) through which the OSD's EncodeService packs stripes
+from many objects into one launch.
 """
 
 from __future__ import annotations
 
 import errno
 from collections import OrderedDict
-from typing import Callable, Iterable, Mapping, Sequence
+from typing import Callable, Iterable, Mapping, NamedTuple, Sequence
 
 import numpy as np
 
@@ -99,6 +101,29 @@ def profile_to_string(profile: ErasureCodeProfile, name: str, default: str) -> s
         profile[name] = default
         return default
     return value
+
+
+class PlanePlan(NamedTuple):
+    """One erasure signature's launch, as a codec with the plane API
+    (`plane_plan` / `planes`) builds it once and runs it per batch."""
+
+    #: logical chunk ids the program reads, in the order of its input rows
+    inputs: tuple
+    #: logical chunk ids it rebuilds
+    targets: tuple
+    #: the rows of the program's output that hold them
+    rows: tuple
+    #: order groups the program runs (1 for a single GF(2^8) matrix)
+    groups: int
+    #: whether the matrices are packed for the Pallas kernel
+    pallas: bool
+    #: the kernel that serves the plan, as a batch span's `path` tag
+    path: str
+    #: (op, n) of a launch shape (op, path, k, n, width): which compiled
+    #: program the plan runs, besides its k input rows and its width
+    program: tuple
+    #: the program's array arguments
+    args: tuple
 
 
 class ErasureCode:
@@ -230,6 +255,68 @@ class ErasureCode:
         `present`: survivors (batch, >=k, chunk) -> (batch, len(targets), chunk).
         """
         raise NotImplementedError
+
+    # -- plane API layout (shared by the codecs that implement plane_plan) ---
+    #
+    # A batch of objects is one (rows, sub_chunk_count, W) int32 array: each
+    # object's chunk cut into its sub-chunk planes, the objects side by side
+    # along W. Every step of a plane program is column-wise, so objects of
+    # different sizes share a launch.
+
+    def plan_survivors(self, present: Sequence[int]) -> tuple:
+        """The survivors among `present` (ascending logical ids) that a
+        plane_plan over them depends on: decodes that agree on these and
+        on their targets share one plan, hence one launch."""
+        return tuple(present)
+
+    def plane_bucket(self, width: int) -> int:
+        """The plane width (int32 words per plane row) that a batch of
+        `width` words per plane row is padded to: its whole-chunk width,
+        sub_chunk_count * width, taken up to a power of two of at least
+        256 words. One compiled program then serves every batch whose
+        chunks fall in the bucket; padded columns are zero and code to
+        zero."""
+        S = self.get_sub_chunk_count()
+        whole = max(256, 1 << (S * width - 1).bit_length())
+        return -(-whole // S)
+
+    def pack_planes(self, objects) -> tuple[np.ndarray, list[int]]:
+        """The plane program's input for a batch: objects[j] holds object
+        j's chunks of plan.inputs, buffers of one length per object. Each
+        chunk is cut into its sub-chunk planes and the objects lie side by
+        side along W, padded to plane_bucket. Returns the (rows,
+        sub_chunk_count, W) int32 words and each object's width in words
+        per plane."""
+        S = self.get_sub_chunk_count()
+        widths = []
+        for chunks in objects:
+            if len(chunks[0]) % (4 * S):
+                raise ErasureCodeError(
+                    errno.EINVAL, f"chunk size {len(chunks[0])} is not a "
+                    f"multiple of 4 * sub_chunk_count = {4 * S}")
+            widths.append(len(chunks[0]) // (4 * S))
+        words = np.zeros((len(objects[0]), S, self.plane_bucket(sum(widths))),
+                         dtype=np.int32)
+        off = 0
+        for chunks, w in zip(objects, widths):
+            for i, chunk in enumerate(chunks):
+                words[i, :, off: off + w] = np.frombuffer(
+                    chunk, dtype="<i4").reshape(S, w)
+            off += w
+        return words, widths
+
+    @staticmethod
+    def unpack_planes(out: np.ndarray, widths: list[int]) -> list:
+        """Per object, its (rows, chunk) uint8 chunks out of a plane
+        program's (rows, sub_chunk_count, W) result for a batch that
+        pack_planes laid out."""
+        objs, off = [], 0
+        for w in widths:
+            objs.append(np.ascontiguousarray(
+                out[:, :, off: off + w]).view(np.uint8).reshape(
+                    out.shape[0], -1))
+            off += w
+        return objs
 
     # -- byte-level API (reference-compatible) ------------------------------
 

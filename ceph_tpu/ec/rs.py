@@ -19,7 +19,6 @@ from __future__ import annotations
 import errno
 from typing import Sequence
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -29,6 +28,7 @@ from ceph_tpu.ec.interface import (
     DecodeTableCache,
     ErasureCode,
     ErasureCodeError,
+    PlanePlan,
     align_up,
     chunk_size_isa_style,
     chunk_size_jerasure_style,
@@ -38,7 +38,7 @@ from ceph_tpu.ec.interface import (
 )
 from ceph_tpu.ops import gf_bitplane as bp
 from ceph_tpu.ops import gf_pallas as gp
-from ceph_tpu.ops.gf import matrix_to_bitmatrix
+from ceph_tpu.ops.gf import gf_region_matmul, matrix_to_bitmatrix
 
 LARGEST_VECTOR_WORDSIZE = 16  # reference: ErasureCodeJerasure.cc:30
 
@@ -82,7 +82,10 @@ class ErasureCodeRs(ErasureCode):
         self._gen: np.ndarray | None = None
         self._encode_bits: jnp.ndarray | None = None
         self._encode_packed: jnp.ndarray | None = None
+        #: decode tables and plane plans, per erasure signature
         self._decode_cache = DecodeTableCache()
+        #: plane plans built (the EncodeService counts cache hits by it)
+        self.plans_built = 0
 
     # -- profile ------------------------------------------------------------
 
@@ -209,6 +212,11 @@ class ErasureCodeRs(ErasureCode):
         bits, _ = self.decode_bitmatrix(present, targets)
         return bp.gf_matmul_bitplane(bits, survivors)
 
+    def encode_bitmatrix(self):
+        """The parity rows' (bitplane, packed) pair, as decode_bitmatrix
+        gives a decode's."""
+        return self._encode_bits, self._encode_packed
+
     # -- planar word API: the fused Pallas fast path --------------------------
 
     def encode_words(self, words) -> jnp.ndarray:
@@ -224,7 +232,7 @@ class ErasureCodeRs(ErasureCode):
             return gp.xor_reduce_words(words)
         if gp.available():
             return gp.gf_matmul_packed(self._encode_packed, words)
-        return self._words_fallback(self._encode_bits, words)
+        return bp.gf_matmul_words(self._encode_bits, words)
 
     def decode_words(self, present, targets, words) -> jnp.ndarray:
         """Planar decode: words holds the first k survivor chunks (logical ids
@@ -235,14 +243,65 @@ class ErasureCodeRs(ErasureCode):
         bits, packed = self.decode_bitmatrix(present, targets)
         if gp.available():
             return gp.gf_matmul_packed(packed, words)
-        return self._words_fallback(bits, words)
+        return bp.gf_matmul_words(bits, words)
 
-    @staticmethod
-    def _words_fallback(bits, words) -> jnp.ndarray:
-        """XLA path for planar words on non-TPU backends (bit-exact, slower)."""
-        bytes_ = jax.lax.bitcast_convert_type(words, jnp.uint8)  # (k, N4, 4)
-        flat = bytes_.reshape(words.shape[0], -1)
-        out = bp.gf_matmul_bitplane(bits, flat[None])[0]
-        return jax.lax.bitcast_convert_type(
-            out.reshape(out.shape[0], -1, 4), jnp.int32
-        )
+    # -- plane API: the EncodeService's batches --------------------------------
+
+    def plan_survivors(self, present) -> tuple:
+        """A plan reads the first k survivors and depends on no other."""
+        return tuple(present)[: self.k]
+
+    def plane_plan(self, present, targets) -> PlanePlan:
+        """The launch that rebuilds logical chunks `targets` from the first
+        k of logical chunks `present`: one plane, one GF(2^8) matrix. On
+        the TPU the matrix is packed for the fused Pallas kernel (the
+        encode's is the one encode_words uses, a decode's comes from
+        decode_bitmatrix); elsewhere it is the byte matrix of the numpy
+        table path, or None where the parity row is a plain XOR. Encode
+        is the plan whose present chunks are the data chunks and whose
+        targets are the parity chunks. Memoized per erasure signature."""
+        present = self.plan_survivors(present)
+        targets = tuple(targets)
+        if len(present) < self.k:
+            raise ErasureCodeError(errno.EIO, "not enough survivors")
+
+        def build():
+            self.plans_built += 1
+            pallas = gp.available()
+            n = self.get_chunk_count()
+            encode = (present == tuple(range(self.k))
+                      and targets == tuple(range(self.k, n)))
+            if encode and self._xor_ok:
+                mat = None
+            elif encode:
+                mat = self._encode_packed if pallas else self._gen[self.k:]
+            elif pallas:
+                mat = self.decode_bitmatrix(present, targets)[1]
+            else:
+                mat = matrices.decode_matrix(
+                    self._gen, self.k, list(present), list(targets))
+            return PlanePlan(
+                inputs=present, targets=targets,
+                rows=tuple(range(len(targets))), groups=1, pallas=pallas,
+                path="pallas" if pallas else "numpy",
+                program=("encode", n) if encode else (
+                    "decode", len(targets)),
+                args=(mat,))
+
+        return self._decode_cache.get_or(("plan", present, targets), build)
+
+    def planes(self, plan: PlanePlan, words) -> np.ndarray:
+        """Run `plan` on (k, 1, W) int32 words whose row j holds chunk
+        plan.inputs[j] of every object of a batch, side by side along W.
+        Returns (len(plan.targets), 1, W) int32 in host memory."""
+        (mat,) = plan.args
+        rows = np.asarray(words).reshape(len(plan.inputs), -1)
+        if plan.pallas:
+            rows = jnp.asarray(rows, dtype=jnp.int32)
+            out = np.asarray(gp.xor_reduce_words(rows) if mat is None
+                             else gp.gf_matmul_packed(mat, rows))
+        else:
+            data = rows.view(np.uint8)
+            out = (np.bitwise_xor.reduce(data, axis=0)[None] if mat is None
+                   else gf_region_matmul(mat, data)).view("<i4")
+        return out[:, None]

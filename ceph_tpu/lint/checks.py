@@ -501,8 +501,7 @@ def check_knob_inventory(project: ProjectContext) -> Iterator[Finding]:
     # when the lint invocation targets just ceph_tpu/ + tests/
     import glob
     import os
-    for aux in (glob.glob(os.path.join(project.root, "tools", "*.py"))
-                + [os.path.join(project.root, "bench.py")]):
+    for aux in glob.glob(os.path.join(project.root, "tools", "*.py")):
         if os.path.abspath(aux) in seen_paths or not os.path.isfile(aux):
             continue
         try:

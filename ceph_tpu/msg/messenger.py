@@ -212,7 +212,7 @@ class Connection:
         #: cluster map at connect() time — tried before TCP when set
         self.local_hint: str | None = None
         #: transport this session actually rides: "tcp", "uds", or "shm"
-        #: (surfaced as a span tag and in daemon_bench's `stack` key)
+        #: (surfaced as a span tag)
         self.stack: str = "tcp"
         self.peer_name: str | None = None
         self.peer_nonce: int = 0

@@ -33,6 +33,7 @@ __all__ = [
     "unpack_bits",
     "pack_bits",
     "gf_matmul_bitplane",
+    "gf_matmul_words",
     "xor_reduce",
 ]
 
@@ -95,6 +96,19 @@ def gf_matmul_bitplane(bitmat: jnp.ndarray, data: jnp.ndarray) -> jnp.ndarray:
     weights = (jnp.int32(1) << jnp.arange(8, dtype=jnp.int32))[:, None, None]
     out = (acc * weights[None]).sum(axis=1).astype(jnp.uint8)  # (r, B, L)
     return jnp.moveaxis(out, 1, 0)
+
+
+def gf_matmul_words(bitmat: jnp.ndarray, words: jnp.ndarray) -> jnp.ndarray:
+    """(8r, 8k) bit-matrix x (k, N4) int32 words -> (r, N4) int32 words:
+    the packed-word layout of ceph_tpu.ops.gf_pallas.gf_matmul_packed on
+    the XLA bit-plane path, for backends without the Pallas kernel
+    (bit-exact, slower)."""
+    bytes_ = jax.lax.bitcast_convert_type(words, jnp.uint8)  # (k, N4, 4)
+    flat = bytes_.reshape(words.shape[0], -1)
+    out = gf_matmul_bitplane(bitmat, flat[None])[0]
+    return jax.lax.bitcast_convert_type(
+        out.reshape(out.shape[0], -1, 4), jnp.int32
+    )
 
 
 @jax.jit

@@ -1,12 +1,11 @@
-"""EncodeService: coalesce concurrent per-object EC work into planar
-TPU launches.
+"""EncodeService: coalesce concurrent per-object EC work into one device
+launch per batch.
 
 SURVEY §7 calls packing "stripes from many concurrent objects" into one
 launch the real performance design problem: a 4 KiB object write encodes
 512 B chunks — far too small to feed the MXU — but N concurrent writes
-stacked end-to-end along the chunk axis are one wide (k, N·chunk/4) planar
-`encode_words` call on the fused Pallas kernel (ceph_tpu.ops.gf_pallas).
-The reference's analogue is ECBackend's op pipelining (start_rmw batches
+laid side by side along the chunk axis are one wide launch. The
+reference's analogue is ECBackend's op pipelining (start_rmw batches
 in-flight ops, ECBackend.cc:1830) feeding ISA-L's wide SIMD units.
 
 Mechanics: the first enqueue arms a latency-bound flush (the batch
@@ -15,12 +14,16 @@ client ops on the 4 op shards, recovery decodes, scrub rebuilds — rides
 the same launch. `launches`/`objects` counters let tests assert the
 coalescing actually happened (objects >> launches under concurrency).
 
-CLAY codecs batch through the same queues with their plane API
-(ErasureCodeClay.plane_plan / planes): each object's chunks are cut into
-their q^t planes and laid side by side along the plane axis, and the whole
-layered decode of the batch is one device program, encode included. Codecs
-with neither API (LRC and SHEC compositions) fall back to their per-object
-paths transparently.
+Every codec that batches does so through its plane API (ErasureCode's
+plane_plan / planes, with the shared pack_planes / unpack_planes layout):
+per batch the service builds the erasure signature's plan, packs the
+objects' chunks side by side, makes one launch and hands each caller its
+chunks. The codec's plan chooses the kernel (the fused Pallas kernel on
+the TPU, numpy tables elsewhere, CLAY's layered program); the service
+chooses only between that and the device mesh, for single-plane plans
+(one GF(2^8) matrix over byte columns) wide enough to pay for it. Codecs
+without the plane API (LRC and SHEC compositions) take their per-object
+paths.
 """
 
 from __future__ import annotations
@@ -31,21 +34,7 @@ import numpy as np
 
 from ceph_tpu.common import tracer as tracing
 from ceph_tpu.common.perf_counters import PerfCounters
-from ceph_tpu.ops import gf_pallas as gp
-from ceph_tpu.ops.gf import gf_region_matmul
-
-
-def _bucket_pad(words: np.ndarray) -> tuple[np.ndarray, int]:
-    """Pad the planar width up to a power-of-2 bucket so batches of
-    varying composition reuse a handful of compiled kernels instead of
-    jitting per width (zero columns encode to zero parity; sliced off)."""
-    w = words.shape[-1]
-    bucket = max(256, 1 << (w - 1).bit_length())
-    if bucket == w:
-        return words, w
-    padded = np.zeros((*words.shape[:-1], bucket), dtype=words.dtype)
-    padded[..., :w] = words
-    return padded, w
+from ceph_tpu.parallel import sharding
 
 
 def _has_planes(codec, blocksize: int) -> bool:
@@ -94,8 +83,8 @@ class EncodeService:
         #: executable, the objects' and the launch's planar bytes, and
         #: the pack, device and unpack legs
         self.tracer = tracer
-        #: (op, path, k, n, bucket width) planar shapes already launched,
-        #: op "encode" or "decode" — a first launch at a shape pays the
+        #: (op, path, k, n, bucket width) shapes already launched, op and
+        #: n from the plan's program — a first launch at a shape pays the
         #: jit compile, and the paths say which kernel served the batches
         self._seen_shapes: set[tuple] = set()
         #: seconds the first op of a batch waits for company
@@ -154,28 +143,26 @@ class EncodeService:
         return self._mesh_cache
 
     def device_path(self) -> str:
-        """Which kernel this process's launches take, for the boot log:
-        the mesh at or above mesh_min_bytes on a multi-device host, the
-        Pallas kernel on one TPU, numpy off the chip."""
+        """Where this process's launches run, for the boot log: the mesh
+        at or above mesh_min_bytes on a multi-device host, else the
+        kernel each codec's plan names for the device."""
         import jax
 
         devs = jax.devices()
-        single = "pallas" if gp.available() else "numpy"
         where = f"{len(devs)} x {devs[0].device_kind}"
         if len(devs) > 1:
             return (
                 f"mesh for batches >= {self.mesh_min_bytes} B, "
-                f"{single} below ({where})"
+                f"the codec's kernel below ({where})"
             )
-        return f"{single} ({where})"
+        return f"the codec's kernel ({where})"
 
     # -- encode ---------------------------------------------------------------
 
     async def encode(self, codec, data: bytes) -> dict[int, bytes]:
         """All k+m chunks for one object, batched across callers."""
         blocksize = codec.get_chunk_size(len(data))
-        if not _has_planes(codec, blocksize) and (
-                not hasattr(codec, "encode_words") or blocksize % 4):
+        if not _has_planes(codec, blocksize):
             self.launches += 1
             self.objects += 1
             return codec.encode(range(codec.get_chunk_count()), data)
@@ -222,133 +209,81 @@ class EncodeService:
 
     def _flush_encode_inner(self, key, q, codec, k, n, sp) -> None:
         try:
-            # pack every object's chunk j end-to-end into planar row j
-            rows: list[list[np.ndarray]] = [[] for _ in range(k)]
-            for data, bs, _fut in q:
+            plan = self._plan(codec, range(k), range(k, n))
+            data = []
+            for obj, bs, _fut in q:
                 padded = np.zeros(k * bs, dtype=np.uint8)
-                padded[: len(data)] = np.frombuffer(data, dtype=np.uint8)
-                for i in range(k):
-                    rows[i].append(padded[i * bs: (i + 1) * bs])
-            if hasattr(codec, "plane_plan"):
-                plan = self._plan(codec, range(k), range(k, n))
-                parity = self._launch_planes(
-                    sp, codec, plan, [[r[j] for r in rows]
-                                      for j in range(len(q))])
-                self._resolve_encode(q, codec, k, n, rows, lambda j, i, off,
-                                     bs: parity[j][i])
-                _tag_unpacked(sp)
-                return
-            planes = np.stack([np.concatenate(r) for r in rows])
-            mesh = self._mesh(planes.shape[1])
-            path, bucket = "numpy", planes.shape[1]
-            if mesh is not None:
-                from ceph_tpu.parallel import sharding
-
-                padded, width = _bucket_pad(planes)
-                path, bucket = "mesh", padded.shape[-1]
-                self._note_launch(sp, "encode", path, k, n, bucket, len(q),
-                                  planes.nbytes, padded.nbytes)
-                parity = _device_leg(sp, lambda: sharding.mesh_encode_planar(
-                    codec, padded, mesh
-                ))[:, :width]
-                self.mesh_launches += 1
-            elif gp.available():
-                words = np.stack(
-                    [np.concatenate(r).view(np.int32) for r in rows]
-                )
-                words, width = _bucket_pad(words)
-                path, bucket = "pallas", words.shape[-1]
-                self._note_launch(sp, "encode", path, k, n, bucket, len(q),
-                                  planes.nbytes, words.nbytes)
-                parity = _device_leg(sp, lambda: np.asarray(
-                    codec.encode_words(words)
-                ))[:, :width].view(np.uint8)
-                parity = parity.reshape(codec.m, -1)
-            else:
-                # off-device: exact table-driven numpy planar path — no
-                # jit-per-width (tiny batches would otherwise recompile
-                # for every composition)
-                parity_mat = codec._gen[codec.k:]
-                self._note_launch(sp, "encode", path, k, n, bucket, len(q),
-                                  planes.nbytes, planes.nbytes)
-                if getattr(codec, "_xor_ok", False):
-                    parity = _device_leg(sp, lambda: np.bitwise_xor.reduce(
-                        planes, axis=0
-                    )[None])
-                else:
-                    parity = _device_leg(
-                        sp, lambda: gf_region_matmul(parity_mat, planes))
-            self._resolve_encode(q, codec, k, n, rows, lambda j, i, off,
-                                 bs: parity[i, off: off + bs])
+                padded[: len(obj)] = np.frombuffer(obj, dtype=np.uint8)
+                data.append(padded.reshape(k, bs))
+            parity = self._launch(
+                sp, codec, plan, data,
+                lambda planes, mesh: sharding.mesh_encode_planar(
+                    codec, planes, mesh))
+            self.launches += 1
+            self.objects += len(q)
+            for rows, out, (_obj, _bs, fut) in zip(data, parity, q):
+                chunks = {codec.chunk_index(i): rows[i].tobytes()
+                          for i in range(k)}
+                for logical, row in zip(plan.targets, out):
+                    chunks[codec.chunk_index(logical)] = row.tobytes()
+                if not fut.done():
+                    fut.set_result(chunks)
             _tag_unpacked(sp)
         except Exception as e:
-            for _data, _bs, fut in q:
+            for _obj, _bs, fut in q:
                 if not fut.done():
                     fut.set_exception(e)
 
-    def _resolve_encode(self, q, codec, k, n, rows, parity_of) -> None:
-        """Hand every caller its k + m chunks: parity_of(j, i, off, bs) is
-        parity row i of object j, whose chunks start `off` bytes into the
-        launch's planar rows."""
-        self.launches += 1
-        self.objects += len(q)
-        off = 0
-        for j, (data, bs, fut) in enumerate(q):
-            chunks: dict[int, bytes] = {}
-            for logical in range(k):
-                chunks[codec.chunk_index(logical)] = rows[logical][j].tobytes()
-            for logical in range(k, n):
-                chunks[codec.chunk_index(logical)] = parity_of(
-                    j, logical - k, off, bs).tobytes()
-            off += bs
-            if not fut.done():
-                fut.set_result(chunks)
-
     def _plan(self, codec, present, targets):
-        """The codec's plan for a plane-API launch, counted in `perf`."""
+        """The codec's plan for a launch, counted in `perf`."""
         built = codec.plans_built
         plan = codec.plane_plan(present, targets)
         self.perf.inc("plane_plans_built" if codec.plans_built != built
                       else "plane_plans_cached")
         return plan
 
-    def _launch_planes(self, sp, codec, plan, objects) -> list:
-        """One launch of a plane-API codec's device program over a batch:
-        objects[j] holds object j's chunks of plan.inputs, laid out by
-        codec.pack_planes (padded to the bucket of the batch's whole-chunk
-        width, as _bucket_pad pads an RS batch). Returns, per object, its
-        rebuilt chunks of plan.targets as (len(targets), chunk) uint8."""
+    def _launch(self, sp, codec, plan, objects, on_mesh) -> list:
+        """One launch over a batch: objects[j] holds object j's chunks of
+        plan.inputs, laid out by codec.pack_planes (padded to the bucket
+        of the batch's whole-chunk width). A single-plane plan is one
+        GF(2^8) matrix over byte columns, so a batch at least
+        mesh_min_bytes wide folds onto the device mesh through on_mesh(
+        (k, W) uint8 rows, mesh); every other batch runs codec.planes.
+        Returns, per object, its chunks of plan.targets as
+        (len(targets), chunk) uint8."""
         words, widths = codec.pack_planes(objects)
-        k, S = words.shape[0], words.shape[1]
-        # one executable per bucket serves encode and every decode
-        self._note_launch(sp, "layered", "clay", k, codec.m,
-                          S * words.shape[-1], len(objects),
-                          4 * k * S * sum(widths), words.nbytes)
-        if sp is not None:
-            sp.set_tag("planes", S)
-            sp.set_tag("groups", plan.groups)
-        out = _device_leg(sp, lambda: codec.planes(plan, words))
-        return codec.unpack_planes(out, widths)
-
-    def _note_launch(self, sp, op: str, path: str, k: int, n: int,
-                     bucket: int, batch: int, object_bytes: int,
-                     launch_bytes: int) -> None:
-        """Tag the batch span with the compile-vs-execute split: a
-        planar shape's FIRST launch pays the jit compile, later ones
-        reuse the cached executable — the difference dominates tail
-        latency and must be attributable in a trace. object_bytes are
-        the batch's planar bytes over all input rows, launch_bytes the
-        same rows padded to the bucket: the launch's padded share."""
-        shape = (op, path, k, n, bucket)
+        k, S, W = words.shape
+        object_bytes = 4 * k * S * sum(widths)
+        mesh = self._mesh(object_bytes // k) if S == 1 else None
+        op, n = plan.program
+        if mesh is None:
+            path, width = plan.path, S * W
+            launch = lambda: codec.planes(plan, words)
+        else:
+            path, width = "mesh", 4 * W
+            launch = lambda: on_mesh(
+                words.reshape(k, -1).view(np.uint8), mesh).view("<i4")[:, None]
+        # a shape's first launch pays the jit compile, later ones reuse
+        # the cached executable: the difference dominates tail latency
+        # and must be attributable in a trace
+        shape = (op, path, k, n, width)
         fresh = shape not in self._seen_shapes
         self._seen_shapes.add(shape)
         if sp is not None:
             sp.set_tag("path", path)
-            sp.set_tag("width", bucket)
+            sp.set_tag("width", width)
             sp.set_tag("compile", fresh)
-            sp.set_tag("batch", batch)
+            sp.set_tag("batch", len(objects))
+            # the input rows' bytes, and the same rows padded to the
+            # bucket: the launch's padded share
             sp.set_tag("object_bytes", object_bytes)
-            sp.set_tag("launch_bytes", launch_bytes)
+            sp.set_tag("launch_bytes", words.nbytes)
+            sp.set_tag("planes", S)
+            sp.set_tag("groups", plan.groups)
+        out = _device_leg(sp, launch)
+        if mesh is not None:
+            self.mesh_launches += 1
+        return codec.unpack_planes(out, widths)
 
     # -- decode ---------------------------------------------------------------
 
@@ -362,17 +297,12 @@ class EncodeService:
         if want <= have:
             return {i: bytes(chunks[i]) for i in want}
         blocksize = len(next(iter(chunks.values())))
-        planes = _has_planes(codec, blocksize)
-        if not planes and (
-                not hasattr(codec, "decode_words") or blocksize % 4):
+        if not _has_planes(codec, blocksize):
             self.launches += 1
             self.objects += 1
             return codec.decode(want, chunks)
-        # a plane-API plan reads every survivor it is offered (it picks
-        # k of them); an RS decode reads the first k
-        present = tuple(
-            sorted(codec.logical_index(p) for p in have)
-        )[: None if planes else codec.k]
+        present = codec.plan_survivors(
+            sorted(codec.logical_index(p) for p in have))
         targets = tuple(
             sorted(codec.logical_index(p) for p in want - have)
         )
@@ -437,81 +367,25 @@ class EncodeService:
     def _flush_decode_inner(self, key, q, codec, sp) -> None:
         codec_id, present, targets = key
         try:
-            if hasattr(codec, "plane_plan"):
-                plan = self._plan(codec, present, targets)
-                rebuilt = self._launch_planes(sp, codec, plan, [
+            plan = self._plan(codec, present, targets)
+            rebuilt = self._launch(
+                sp, codec, plan, [
                     [chunks[codec.chunk_index(i)] for i in plan.inputs]
-                    for chunks, _bs, _want, _fut in q])
-                self._resolve_decode(q, codec, targets, lambda j, t, off,
-                                     bs: rebuilt[j][t])
-                _tag_unpacked(sp)
-                return
-            rows: list[list[np.ndarray]] = [[] for _ in present]
-            for chunks, bs, _want, _fut in q:
-                for i, logical in enumerate(present):
+                    for chunks, _bs, _want, _fut in q],
+                lambda planes, mesh: sharding.mesh_decode_planar(
+                    codec, list(plan.inputs), list(targets), planes, mesh))
+            self.launches += 1
+            self.objects += len(q)
+            for (chunks, _bs, want, fut), out in zip(q, rebuilt):
+                res = {i: bytes(chunks[i]) for i in want if i in chunks}
+                for logical, row in zip(targets, out):
                     phys = codec.chunk_index(logical)
-                    rows[i].append(
-                        np.frombuffer(chunks[phys], dtype=np.uint8)
-                    )
-            planes = np.stack([np.concatenate(r) for r in rows])
-            mesh = self._mesh(planes.shape[1])
-            k, n = codec.k, len(targets)
-            if mesh is not None:
-                from ceph_tpu.parallel import sharding
-
-                padded, width = _bucket_pad(planes)
-                self._note_launch(
-                    sp, "decode", "mesh", k, n, padded.shape[-1], len(q),
-                    planes.nbytes, padded.nbytes)
-                rebuilt = _device_leg(sp, lambda: sharding.mesh_decode_planar(
-                    codec, list(present), list(targets), padded, mesh
-                ))[:, :width]
-                self.mesh_launches += 1
-            elif gp.available():
-                words = np.stack(
-                    [np.concatenate(r).view(np.int32) for r in rows]
-                )
-                words, width = _bucket_pad(words)
-                self._note_launch(
-                    sp, "decode", "pallas", k, n, words.shape[-1], len(q),
-                    planes.nbytes, words.nbytes)
-                rebuilt = _device_leg(sp, lambda: np.asarray(
-                    codec.decode_words(
-                        list(present), list(targets), words
-                    )
-                ))[:, :width].view(np.uint8).reshape(len(targets), -1)
-            else:
-                from ceph_tpu.ec import matrices
-
-                self._note_launch(
-                    sp, "decode", "numpy", k, n, planes.shape[1], len(q),
-                    planes.nbytes, planes.nbytes)
-                dm = matrices.decode_matrix(
-                    codec._gen, codec.k, list(present), list(targets)
-                )
-                rebuilt = _device_leg(
-                    sp, lambda: gf_region_matmul(dm, planes))
-            self._resolve_decode(q, codec, targets, lambda j, t, off,
-                                 bs: rebuilt[t, off: off + bs])
+                    if phys in want:
+                        res[phys] = row.tobytes()
+                if not fut.done():
+                    fut.set_result(res)
             _tag_unpacked(sp)
         except Exception as e:
             for _c, _b, _w, fut in q:
                 if not fut.done():
                     fut.set_exception(e)
-
-    def _resolve_decode(self, q, codec, targets, rebuilt_of) -> None:
-        """Hand every caller the chunks it wants: rebuilt_of(j, t, off,
-        bs) is target t of object j, whose chunks start `off` bytes into
-        the launch's planar rows."""
-        self.launches += 1
-        self.objects += len(q)
-        off = 0
-        for j, (chunks, bs, want, fut) in enumerate(q):
-            out = {i: bytes(chunks[i]) for i in want if i in chunks}
-            for t, logical in enumerate(targets):
-                phys = codec.chunk_index(logical)
-                if phys in want:
-                    out[phys] = rebuilt_of(j, t, off, bs).tobytes()
-            off += bs
-            if not fut.done():
-                fut.set_result(out)

@@ -69,7 +69,8 @@ def sharded_encode(ec, data, mesh: Mesh):
     Pure map over shards: every device encodes its (batch/S, k, chunk/B)
     block with the single-chip kernel; no collectives needed.
     """
-    return _sharded_matmul(mesh)(ec._encode_bits, data)
+    bits, _ = ec.encode_bitmatrix()
+    return _sharded_matmul(mesh)(bits, data)
 
 
 def sharded_decode(ec, present, targets, survivors, mesh: Mesh):

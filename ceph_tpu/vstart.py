@@ -25,7 +25,6 @@ Why this exists: through round 4 every "live" test hosted all daemons in ONE
 interpreter on one loop — fine for correctness, but a single GIL serialised
 the whole data path (~27 MB/s).  Real processes give each OSD its own
 interpreter, so daemon-path throughput can scale with the process count;
-``tools/daemon_bench.py --multiprocess`` measures exactly that and
 ``tests/test_multiprocess.py`` proves kill/revive correctness across real
 PIDs (SIGKILL, not cooperative ``stop()``).
 """

@@ -5,8 +5,8 @@ One process owns the chip and runs five phases through the entry points a
 user calls, at the sizes users run:
 
   1. device   the chip JAX reports; no CPU fallback
-  2. kernel   RS(8,3) encode_words/decode_words on 256 MiB (bench.py's
-              launch), checked against the numpy GF(2^8) oracle
+  2. kernel   RS(8,3) encode_words/decode_words on 256 MiB in one
+              launch, checked against the numpy GF(2^8) oracle
   3. served   mons + 12 OSDService daemons + a Rados client over TCP; an
               RS(8,3) plugin=tpu pool takes >= 256 MiB of 4 KiB-4 MiB
               objects, 32 writes in flight; everything is read back, then

@@ -79,7 +79,7 @@ def _fits_one_chip(compiled) -> bool:
     "k,m,lost,words",
     [
         (8, 3, (), 65536),
-        # bench.py's 256 MiB launch
+        # chip_smoke.py's 256 MiB kernel launch
         (8, 3, (), 8 * 1024 * 1024),
         (8, 3, (0, 1, 2), 8 * 1024 * 1024),
         # a degraded read with one OSD down rebuilds one chunk
@@ -155,6 +155,29 @@ def test_crush_indep_stage_compiles_for_v5e(one_chip, no_cache_reads, x64):
         recurse_to_leaf=False, tries=100, recurse_tries=5,
     ).compile()
     assert _fits_one_chip(compiled)
+
+
+def test_rs_planes_compile_for_v5e(one_chip, no_cache_reads, x64,
+                                   monkeypatch):
+    """What RS(8,3)'s plane API launches on the chip, at one bucket: the
+    encode plan's and a one-chunk rebuild's matrices on a batch of
+    (k, bucket) words, through the fused kernel."""
+    ec = factory("isa", {"k": "8", "m": "3", "technique": "cauchy"})
+    # the plans' matrices packed for the kernel, as on the chip
+    monkeypatch.setattr(gp, "available", lambda: True)
+    bucket = ec.plane_bucket(5000)
+    assert bucket == 8192
+    for present, targets in ((range(8), range(8, 11)),
+                             ([i for i in range(11) if i != 5], [5])):
+        plan = ec.plane_plan(present, targets)
+        assert (plan.path, plan.pallas) == ("pallas", True)
+        (mat,) = plan.args
+        compiled = gp.gf_matmul_packed.lower(
+            jax.ShapeDtypeStruct(mat.shape, mat.dtype, sharding=one_chip),
+            jax.ShapeDtypeStruct((8, bucket), jnp.int32, sharding=one_chip),
+        ).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        assert _fits_one_chip(compiled)
 
 
 @pytest.mark.parametrize(

@@ -115,7 +115,7 @@ async def _amain(args) -> int:
 
 async def _bench(args) -> dict:
     """Save/restore GB/s against an in-process cluster (no external
-    daemons needed), the `bench.py --ckpt` engine."""
+    daemons needed): the `bench` subcommand."""
     import numpy as np
 
     from tests.test_cluster_live import Cluster, EC_POOL, REP_POOL

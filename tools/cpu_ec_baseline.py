@@ -7,8 +7,8 @@ Two measured numbers for RS(8,3) encode on this host, single thread:
   2. `c-xor`  — tools/ec_cpu_baseline.c: bit-plane XOR-schedule encode in
      64-bit words, the same algorithm class as the reference's jerasure
      bitmatrix techniques (ErasureCodeJerasure.cc:305 prepare_schedule).
-     This is the honest single-core CPU number the TPU path is compared
-     against in bench.py / BASELINE.md.
+     This is the honest single-core CPU number BASELINE.md compares the
+     TPU path against.
 
 Usage: python tools/cpu_ec_baseline.py [--size BYTES_PER_CHUNK] [--iters N]
 Prints one JSON line with both GB/s figures.

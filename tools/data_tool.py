@@ -113,7 +113,7 @@ async def _drain(store, *, seed: int, batch_size: int):
 
 async def _bench(args) -> dict:
     """Ingest + sustained-read throughput against an in-process
-    cluster, the `bench.py --data` engine. The read runs twice — with
+    cluster: the `bench` subcommand. The read runs twice — with
     the prefetch pipeline and with data_prefetch_batches=0 — so the
     line carries its own serial baseline (the >= 2x acceptance bar)."""
     import numpy as np

@@ -400,7 +400,7 @@ async def _bench_parallel(args) -> dict:
 
 async def _bench(args) -> dict:
     """Barrier latency + sharded-restore scaling against an in-process
-    cluster (no external daemons), the `bench.py --fleet` engine."""
+    cluster (no external daemons): the `bench` subcommand."""
     import numpy as np
 
     from tests.test_cluster_live import REP_POOL, Cluster

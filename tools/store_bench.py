@@ -6,7 +6,7 @@ The `ceph daemon osd.N bench` / objectstore fio-plugin role
 backend directly — no messenger, no PG layer — so the store's own write
 and read paths are the only thing on the clock. Reports MB/s per
 (backend, workload, object size) over durable FileDB-backed stores, JSON
-to stdout (bench.py convention) so CI can diff runs:
+to stdout so CI can diff runs:
 
     python tools/store_bench.py
     python tools/store_bench.py --sizes 4096,65536 --bytes-per-case 8388608
