@@ -28,7 +28,12 @@ Pieces:
     debug levels.
   * `_LoopProbe` — one per running event loop while any tracer on it is
     enabled: a `loop_lag` root span every 10 ms, from when the firing was
-    due to when it ran (how late the loop runs every callback).
+    due to when it ran (how late the loop runs every callback), and a
+    `loop_account` root span every second with where the loop's CPU went.
+  * `LoopAccounts` — the probed loop's CPU accounts by layer: `account`
+    (a with-block) and `accounted` (every step of an async function)
+    charge the loop thread's CPU to a named account, exclusively: a
+    nested account's CPU is its own, never also its parent's.
 
 Flight recorder / tail sampling (the Canopy shape, Kaldor et al. 2017):
 with the tracer enabled, EVERY op records spans — head sampling only
@@ -54,6 +59,11 @@ tracer costs one flag check per span site and allocates nothing:
         sp.set_tag("cache", "hit")
         sp.finish()
 
+A CPU account site is the same: while no enabled tracer probes a loop,
+the account registry is empty, and `account()` returns a shared no-op
+context, and an `accounted` function its own coroutine, after one truth
+test of it.
+
 The task-local current context (`use`/`use_wire`) rides a contextvar so
 awaits and `create_task` propagate it without plumbing; `child()`
 returns None when no sampled context is active — interior span sites
@@ -63,11 +73,17 @@ never start traces of their own.
 from __future__ import annotations
 
 import asyncio
+import collections.abc
+import contextlib
 import contextvars
+import functools
 import heapq
+import inspect
 import json
 import os
 import random
+import resource
+import threading
 import time
 import weakref
 from collections import deque
@@ -257,6 +273,168 @@ def _jsonable(v):
 
 #: seconds between two firings of an event loop's lag probe
 LOOP_PROBE_S = 0.010
+#: seconds between two loop_account spans of a probe
+LOOP_ACCOUNT_S = 1.0
+
+
+class LoopAccounts:
+    """Where one event loop's thread spends its CPU, by named account.
+
+    `push(name)` starts charging the thread's CPU (time.thread_time) and
+    wall seconds (time.perf_counter) to `name` and `pop()` stops; they
+    nest as a stack, and only the top account is charged, so every
+    second is counted once. An account wraps synchronous code only: a
+    stretch that awaits would charge whatever else the loop ran meanwhile.
+    The table also works as a with-block (`account(name)` returns it)."""
+
+    __slots__ = ("loop", "_table", "_stack", "_cpu0", "_wall0", "_next")
+
+    def __init__(self, loop=None):
+        self.loop = loop
+        #: account -> [CPU seconds, wall seconds, entries] since the probe
+        #: last took them
+        self._table: dict[str, list] = {}
+        self._stack: list[list] = []
+        self._cpu0 = self._wall0 = 0.0
+        self._next = ""
+
+    def push(self, name: str, count: bool = True) -> None:
+        cpu, wall = _thread_time(), _perf_counter()
+        stack = self._stack
+        if stack:
+            top = stack[-1]
+            top[0] += cpu - self._cpu0
+            top[1] += wall - self._wall0
+        self._cpu0, self._wall0 = cpu, wall
+        row = self._table.get(name)
+        if row is None:
+            row = self._table[name] = [0.0, 0.0, 0]
+        if count:
+            row[2] += 1
+        stack.append(row)
+
+    def pop(self) -> None:
+        cpu, wall = _thread_time(), _perf_counter()
+        top = self._stack.pop()
+        top[0] += cpu - self._cpu0
+        top[1] += wall - self._wall0
+        self._cpu0, self._wall0 = cpu, wall
+
+    def take(self) -> tuple[dict, dict, dict]:
+        """(cpu, wall, n) by account since the last take, and start
+        afresh; an account still open goes on charging its old row."""
+        table, self._table = self._table, {}
+        return ({a: r[0] for a, r in table.items()},
+                {a: r[1] for a, r in table.items()},
+                {a: r[2] for a, r in table.items()})
+
+    def __enter__(self) -> None:
+        self.push(self._next)
+
+    def __exit__(self, *exc) -> bool:
+        self.pop()
+        return False
+
+
+#: thread id -> the accounts of the event loop running on that thread,
+#: while an enabled tracer probes it; empty, every account site is one
+#: truth test
+_accounts: dict[int, LoopAccounts] = {}
+_NO_ACCOUNT = contextlib.nullcontext()
+_get_ident = threading.get_ident
+_running_loop = asyncio._get_running_loop
+_thread_time = time.thread_time
+_perf_counter = time.perf_counter
+
+
+def loop_accounts() -> LoopAccounts | None:
+    """The accounts of the event loop running on this thread, or None
+    where no enabled tracer probes it."""
+    if not _accounts:
+        return None
+    acc = _accounts.get(_get_ident())
+    if acc is None or acc.loop is not _running_loop():
+        return None
+    return acc
+
+
+def account(name: str):
+    """A with-block that charges the loop thread's CPU to account `name`
+    (a layer of PERF.md section 3) while no nested account runs. Without
+    an enabled tracer on the running loop it is a shared no-op context."""
+    if not _accounts:
+        return _NO_ACCOUNT
+    acc = _accounts.get(_get_ident())
+    if acc is None or acc.loop is not _running_loop():
+        return _NO_ACCOUNT
+    acc._next = name
+    return acc
+
+
+class _Stepped(collections.abc.Coroutine):
+    """A coroutine whose every step, from a resume to its next
+    suspension, is charged to one account: the function's own CPU and
+    never the time it waits."""
+
+    __slots__ = ("_coro", "_acc", "_name", "_count")
+
+    def __init__(self, coro, acc: LoopAccounts, name: str):
+        self._coro, self._acc, self._name = coro, acc, name
+        self._count = True
+
+    def send(self, value):
+        acc = self._acc
+        acc.push(self._name, self._count)
+        self._count = False
+        try:
+            return self._coro.send(value)
+        finally:
+            acc.pop()
+
+    def throw(self, *exc):
+        acc = self._acc
+        acc.push(self._name, self._count)
+        self._count = False
+        try:
+            return self._coro.throw(*exc)
+        finally:
+            acc.pop()
+
+    def close(self) -> None:
+        self._coro.close()
+
+    def __await__(self):
+        return self
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        return self.send(None)
+
+    def __getattr__(self, name):
+        # cr_frame, cr_code, __qualname__: what asyncio's reprs and the
+        # benchmark's loop annotations read off a task's coroutine
+        return getattr(self._coro, name)
+
+
+def accounted(name: str):
+    """Decorate an async function so that each call's steps are charged
+    to account `name` while an enabled tracer probes the running loop;
+    otherwise a call returns the function's own coroutine."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            coro = fn(*args, **kwargs)
+            if not _accounts:
+                return coro
+            acc = loop_accounts()
+            return coro if acc is None else _Stepped(coro, acc, name)
+
+        return inspect.markcoroutinefunction(call)
+
+    return wrap
 
 
 class _LoopProbe:
@@ -266,7 +444,16 @@ class _LoopProbe:
     ends when it ran, so its duration is how late the loop ran callbacks
     then (the role of Ceph's heartbeat-map thread timeouts). The span goes
     through the first registered tracer that is still enabled; when none
-    is, the probe stops."""
+    is, the probe stops.
+
+    While it runs, the probe also keeps the loop's CPU accounts (it owns
+    the LoopAccounts every account site of this loop's thread charges)
+    and times the loop's selector `select`, where the loop blocks for
+    lack of work. Every LOOP_ACCOUNT_S a root span `loop_account` carries
+    what changed since the last one: busy_s (wall seconds outside select)
+    and select_s, thread_cpu_s (the loop thread's CPU), the process's
+    proc_user_s, proc_sys_s and minflt (getrusage), and per account
+    cpu_<name>_s, wall_<name>_s and n_<name>."""
 
     def __init__(self, loop: asyncio.AbstractEventLoop):
         self._loop = weakref.ref(loop)
@@ -274,6 +461,15 @@ class _LoopProbe:
         #: when the pending firing is due, on the tracer's clock
         self.due = 0.0
         self.handle: asyncio.TimerHandle | None = None
+        self.accounts: LoopAccounts | None = None
+        self._thread = 0
+        #: wall seconds the loop has spent blocked in select
+        self.select_s = 0.0
+        #: (selector, what its instance held as `select` and `close`
+        #: before) while the wrappers are installed
+        self._selector = None
+        #: the readings the next loop_account span starts from
+        self._mark: tuple | None = None
 
     def serves(self, loop) -> bool:
         return self.handle is not None and self._loop() is loop
@@ -282,6 +478,7 @@ class _LoopProbe:
         if all(r() is not tracer for r in self.tracers):
             self.tracers.append(weakref.ref(tracer))
         if self.handle is None:
+            self._start()
             self._arm()
 
     def drop(self, tracer: "Tracer") -> None:
@@ -289,13 +486,55 @@ class _LoopProbe:
         if not self.tracers:
             self._stop()
 
+    def _start(self) -> None:
+        loop = self._loop()
+        self._thread = threading.get_ident()
+        self.accounts = _accounts[self._thread] = LoopAccounts(loop)
+        sel = getattr(loop, "_selector", None)
+        if sel is not None and self._selector is None:
+            held = {a: sel.__dict__.get(a) for a in ("select", "close")}
+            orig_select, orig_close = sel.select, sel.close
+
+            def select(timeout=None):
+                t0 = time.perf_counter()
+                try:
+                    return orig_select(timeout)
+                finally:
+                    self.select_s += time.perf_counter() - t0
+
+            def close():
+                # the loop is closing: its accounts and wrappers go with
+                # it, whatever tracer is still enabled
+                self._release()
+                return orig_close()
+
+            sel.select, sel.close = select, close
+            self._selector = (sel, held)
+        self._mark = self._readings(now())
+
     def _stop(self) -> None:
         if self.handle is not None:
             self.handle.cancel()
             self.handle = None
+        self._release()
         loop = self._loop()
         if loop is not None and _probes.get(loop) is self:
             del _probes[loop]
+
+    def _release(self) -> None:
+        """Drop the loop's accounts and restore its selector."""
+        if (self.accounts is not None
+                and _accounts.get(self._thread) is self.accounts):
+            del _accounts[self._thread]
+        self.accounts = None
+        if self._selector is not None:
+            sel, held = self._selector
+            for attr, was in held.items():
+                if was is None:
+                    delattr(sel, attr)
+                else:
+                    setattr(sel, attr, was)
+            self._selector = None
 
     def _arm(self) -> None:
         # the due time is read before call_later reads the loop's clock,
@@ -316,7 +555,36 @@ class _LoopProbe:
         sp = on[0].start("loop_lag", start=min(self.due, ran), tail=False)
         if sp is not None:
             sp.finish(end=ran)
+        if ran - self._mark[0] >= LOOP_ACCOUNT_S:
+            self._account(on[0], ran)
         self._arm()
+
+    def _readings(self, at: float) -> tuple:
+        ru = resource.getrusage(resource.RUSAGE_SELF)
+        return (at, time.perf_counter(), self.select_s, time.thread_time(),
+                ru.ru_utime, ru.ru_stime, ru.ru_minflt)
+
+    def _account(self, tracer: "Tracer", ran: float) -> None:
+        prev, cur = self._mark, self._readings(ran)
+        self._mark = cur
+        select_s = cur[2] - prev[2]
+        tags: dict[str, Any] = {
+            "busy_s": cur[1] - prev[1] - select_s,
+            "select_s": select_s,
+            "thread_cpu_s": cur[3] - prev[3],
+            "proc_user_s": cur[4] - prev[4],
+            "proc_sys_s": cur[5] - prev[5],
+            "minflt": cur[6] - prev[6],
+        }
+        cpu, wall, n = self.accounts.take()
+        for name, secs in cpu.items():
+            tags[f"cpu_{name}_s"] = secs
+            tags[f"wall_{name}_s"] = wall[name]
+            tags[f"n_{name}"] = n.get(name, 0)
+        sp = tracer.start("loop_account", tags=tags, start=prev[0],
+                          tail=False)
+        if sp is not None:
+            sp.finish(end=ran)
 
 
 #: event loop -> its lag probe: one per loop, whatever tracers share it,
@@ -571,6 +839,10 @@ class Tracer:
     # -- completion / ring / export -------------------------------------------
 
     def _finished(self, span: Span) -> None:
+        with account("trace"):
+            self._record(span)
+
+    def _record(self, span: Span) -> None:
         if span.sampled:
             self._ring.append(span.dump())
         # the always-on flight ring keeps the Span OBJECT — no dict is

@@ -42,6 +42,7 @@ import struct as struct_mod
 from dataclasses import dataclass
 from enum import IntEnum
 
+from ceph_tpu.common import tracer as tracing
 from ceph_tpu.common.crc import ceph_crc32c
 from ceph_tpu.common.encoding import (
     Decoder,
@@ -128,23 +129,25 @@ class Frame:
         chains across them (crc(AB) == crc32c(crc32c(seed, A), B)) and
         the native crc takes memoryviews in place, so a bulk `raw`
         segment reaches the transport with zero intermediate copies."""
-        segs = self.segments if self.segments is not None else (self.payload,)
-        total = 0
-        crc = 0xFFFFFFFF
-        for s in segs:
-            total += len(s)
-            crc = ceph_crc32c(crc, s)
-        parts: list = [
-            _HEAD.pack(MAGIC, int(self.tag), total),
-            *(s for s in segs if len(s)),
-            _U32.pack(crc),
-        ]
-        if session_key is not None:
-            h = hmac_mod.new(session_key, digestmod=hashlib.sha256)
-            for p in parts:
-                h.update(p)
-            parts.append(h.digest()[:SIG_LEN])
-        return parts
+        with tracing.account("msg_frame"):
+            segs = (self.segments if self.segments is not None
+                    else (self.payload,))
+            total = 0
+            crc = 0xFFFFFFFF
+            for s in segs:
+                total += len(s)
+                crc = ceph_crc32c(crc, s)
+            parts: list = [
+                _HEAD.pack(MAGIC, int(self.tag), total),
+                *(s for s in segs if len(s)),
+                _U32.pack(crc),
+            ]
+            if session_key is not None:
+                h = hmac_mod.new(session_key, digestmod=hashlib.sha256)
+                for p in parts:
+                    h.update(p)
+                parts.append(h.digest()[:SIG_LEN])
+            return parts
 
     def encode(self, session_key: bytes | None = None) -> bytes:
         return b"".join(self.encode_parts(session_key))
@@ -160,6 +163,7 @@ def frame_header_len() -> int:
 _MV_TAGS = frozenset((int(Tag.MESSAGE_SEG), int(Tag.BATCH)))
 
 
+@tracing.accounted("msg_read")
 async def read_frame(reader, session_key: bytes | None = None) -> Frame:
     """Read one frame from an asyncio StreamReader, verifying crc (and the
     signature when the session is signing). The signature and crc are
@@ -388,9 +392,10 @@ def payload_of(msg: Message):
     json.loads so both formats — and old peers — decode identically)."""
     if not len(msg.data):
         return {}
-    if msg.flags & FLAG_BIN_DATA:
-        return decode_payload(msg.data)
-    return json.loads(msg.data)
+    with tracing.account("msg_codec"):
+        if msg.flags & FLAG_BIN_DATA:
+            return decode_payload(msg.data)
+        return json.loads(msg.data)
 
 
 def redirect_reply(

@@ -43,6 +43,7 @@ import random
 import tempfile
 from dataclasses import dataclass, field
 
+from ceph_tpu.common import tracer as tracing
 from ceph_tpu.common.crc import impl as crc_impl
 from ceph_tpu.common.encoding import Decoder, Encoder, encode_payload
 from ceph_tpu.msg.frames import (
@@ -615,86 +616,91 @@ class Connection:
         Message replays correctly to either kind of peer. On the binary
         path the bulk `raw` bytes ride as their own frame segment
         (MESSAGE_SEG) and never pass through an encoder or a join."""
-        m = self.messenger
-        sps = getattr(msg, "_send_spans", None)
-        if sps is not None:
-            for sp in sps:
-                if corked > 1:
-                    sp.set_tag("corked", corked)
-                sp.set_tag("stack", self.stack)
-                sp.finish()
-            msg._send_spans = None  # lossless replays re-encode; once only
-        if not self.policy.lossy and self._ack_owed > self._ack_sent:
-            msg.ack = self._ack_owed
-            self._ack_sent = self._ack_owed
-        m.perf.inc("msgs_out")
-        use_bin = m._env_binary and (
-            self.peer_features & FEATURE_BIN_ENVELOPE
-        )
-        if msg.payload is not None:
-            if use_bin:
-                msg.flags |= FLAG_BIN_DATA
-                msg.data = encode_payload(msg.payload)
-                m.perf.inc("env_binary")
-            else:
-                msg.flags &= ~FLAG_BIN_DATA
-                msg.data = json.dumps(msg.payload).encode()
-                m.perf.inc("env_json")
-        algo = m._compress_algo
-        if algo is None and use_bin:
-            return message_seg_frame(msg)
-        payload = msg.encode()
-        if algo is not None and len(payload) >= m._compress_floor:
-            try:
-                from ceph_tpu.common.compressor import factory
+        with tracing.account("msg_encode"):
+            m = self.messenger
+            sps = getattr(msg, "_send_spans", None)
+            if sps is not None:
+                for sp in sps:
+                    if corked > 1:
+                        sp.set_tag("corked", corked)
+                    sp.set_tag("stack", self.stack)
+                    sp.finish()
+                # lossless replays re-encode; once only
+                msg._send_spans = None
+            if not self.policy.lossy and self._ack_owed > self._ack_sent:
+                msg.ack = self._ack_owed
+                self._ack_sent = self._ack_owed
+            m.perf.inc("msgs_out")
+            use_bin = m._env_binary and (
+                self.peer_features & FEATURE_BIN_ENVELOPE
+            )
+            if msg.payload is not None:
+                if use_bin:
+                    msg.flags |= FLAG_BIN_DATA
+                    with tracing.account("msg_codec"):
+                        msg.data = encode_payload(msg.payload)
+                    m.perf.inc("env_binary")
+                else:
+                    msg.flags &= ~FLAG_BIN_DATA
+                    with tracing.account("msg_codec"):
+                        msg.data = json.dumps(msg.payload).encode()
+                    m.perf.inc("env_json")
+            algo = m._compress_algo
+            if algo is None and use_bin:
+                return message_seg_frame(msg)
+            payload = msg.encode()
+            if algo is not None and len(payload) >= m._compress_floor:
+                try:
+                    from ceph_tpu.common.compressor import factory
 
-                # one ratio policy for wire AND store paths
-                did, packed = factory(algo).maybe_compress(payload)
-            # cephlint: disable=error-taxonomy (unknown/unavailable codec: ship the payload raw)
-            except Exception:
-                did = False  # unknown/unavailable codec: ship raw
-            if did:
-                m.compressed_frames += 1
-                return Frame(
-                    Tag.MESSAGE_COMPRESSED,
-                    Encoder().string(algo).blob(packed).bytes(),
-                )
-        return Frame(Tag.MESSAGE, payload)
+                    # one ratio policy for wire AND store paths
+                    did, packed = factory(algo).maybe_compress(payload)
+                # cephlint: disable=error-taxonomy (unknown/unavailable codec: ship the payload raw)
+                except Exception:
+                    did = False  # unknown/unavailable codec: ship raw
+                if did:
+                    m.compressed_frames += 1
+                    return Frame(
+                        Tag.MESSAGE_COMPRESSED,
+                        Encoder().string(algo).blob(packed).bytes(),
+                    )
+            return Frame(Tag.MESSAGE, payload)
 
     async def _write_loop(self, stream: _InjectingStream) -> None:
         m = self.messenger
         q = self._send_q
         while True:
             items = [await q.get()]
-            # cork: drain whatever else is already queued (bounded) and
-            # ship the whole run as one write — with FRAME_BATCH, as one
-            # OUTER frame whose single crc+HMAC covers every frame in it
-            limit = m._cork_max
-            # byte-capped on ring streams: a run that fits one shm record
-            # is loaned to the receiver zero-copy, while an oversize run
-            # would bounce through the chunked-reassembly path
-            cap = getattr(stream, "max_run_bytes", None)
-            run_bytes = _est_size(items[0])
-            while len(items) < limit and (
-                cap is None or run_bytes < cap
-            ):
-                try:
-                    it = q.get_nowait()
-                except asyncio.QueueEmpty:
-                    break
-                items.append(it)
-                run_bytes += _est_size(it)
-            n = len(items)
-            frames = [
-                self._encode_msg_frame(it, corked=n)
-                if kind == "msg"
-                else it
-                for kind, it in items
-            ]
-            if n > 1 and (self.peer_features & FEATURE_FRAME_BATCH):
-                m.perf.inc("batch_frames")
-                m.perf.inc("batch_inner", n)
-                frames = [make_batch_frame(frames)]
+            with tracing.account("msg_cork"):
+                # cork: drain whatever else is already queued (bounded) and
+                # ship the whole run as one write — with FRAME_BATCH, as one
+                # OUTER frame whose single crc+HMAC covers every frame in it
+                limit = m._cork_max
+                # byte-capped on ring streams: a run that fits one shm record
+                # is loaned to the receiver zero-copy, while an oversize run
+                # would bounce through the chunked-reassembly path
+                cap = getattr(stream, "max_run_bytes", None)
+                run_bytes = _est_size(items[0])
+                while len(items) < limit and (
+                    cap is None or run_bytes < cap
+                ):
+                    try:
+                        it = q.get_nowait()
+                    except asyncio.QueueEmpty:
+                        break
+                    items.append(it)
+                    run_bytes += _est_size(it)
+                n = len(items)
+                frames = [
+                    self._encode_msg_frame(it, corked=n)
+                    if kind == "msg"
+                    else it
+                    for kind, it in items
+                ]
+                if n > 1 and (self.peer_features & FEATURE_FRAME_BATCH):
+                    m.perf.inc("batch_frames")
+                    m.perf.inc("batch_inner", n)
+                    frames = [make_batch_frame(frames)]
             await stream.send_frames(
                 frames, self.session_key, coalesced=n
             )
@@ -708,6 +714,7 @@ class Connection:
             else:
                 await self._process_frame(frame)
 
+    @tracing.accounted("msg_dispatch")
     async def _process_frame(
         self, frame: Frame, batched: bool = False
     ) -> None:
@@ -721,10 +728,11 @@ class Connection:
                 Tag.MESSAGE, factory(algo).decompress(d.blob())
             )
         if frame.tag in (Tag.MESSAGE, Tag.MESSAGE_SEG):
-            if frame.tag is Tag.MESSAGE_SEG:
-                msg = decode_message_seg(frame.payload)
-            else:
-                msg = Message.decode(frame.payload)
+            with tracing.account("msg_decode"):
+                if frame.tag is Tag.MESSAGE_SEG:
+                    msg = decode_message_seg(frame.payload)
+                else:
+                    msg = Message.decode(frame.payload)
             if not self.policy.lossy:
                 # coalesced ack-on-receipt: note what we owe and let
                 # the next outgoing message piggyback it (a timer

@@ -45,6 +45,7 @@ import mmap
 import os
 import struct as struct_mod
 
+from ceph_tpu.common import tracer as tracing
 from ceph_tpu.lint import racecheck
 from ceph_tpu.msg.frames import Frame, read_frame
 from ceph_tpu.msg.stack import InjectingStream
@@ -171,9 +172,11 @@ class ShmRing:
         elif free < need:
             return False
         rec = REC_CHUNKED if chunked_header else len(data)
-        _U32.pack_into(self.buf, DATA_OFF + pos, rec)
-        self.buf[DATA_OFF + pos + 4: DATA_OFF + pos + 4 + len(data)] = data
-        self._store(_OFF_HEAD, head + need)
+        with tracing.account("msg_ring"):
+            _U32.pack_into(self.buf, DATA_OFF + pos, rec)
+            off = DATA_OFF + pos + 4
+            self.buf[off: off + len(data)] = data
+            self._store(_OFF_HEAD, head + need)
         return True
 
     def try_write_parts(self, parts: list, total: int) -> bool:
@@ -196,12 +199,13 @@ class ShmRing:
             pos = 0
         elif free < need:
             return False
-        _U32.pack_into(self.buf, DATA_OFF + pos, total)
-        off = DATA_OFF + pos + 4
-        for p in parts:
-            self.buf[off: off + len(p)] = p
-            off += len(p)
-        self._store(_OFF_HEAD, head + need)
+        with tracing.account("msg_ring"):
+            _U32.pack_into(self.buf, DATA_OFF + pos, total)
+            off = DATA_OFF + pos + 4
+            for p in parts:
+                self.buf[off: off + len(p)] = p
+                off += len(p)
+            self._store(_OFF_HEAD, head + need)
         return True
 
     # -- consumer --------------------------------------------------------------
@@ -347,6 +351,7 @@ class ShmStream(InjectingStream):
             await self._write_record(mv[off: off + n])
             off += n
 
+    @tracing.accounted("msg_send")
     async def send_frames(
         self, frames: list, session_key: bytes | None, coalesced: int = 1
     ) -> None:
@@ -418,6 +423,7 @@ class ShmStream(InjectingStream):
         # a heap buffer, NOT a ring loan: recv() must not treat it as one
         return buf
 
+    @tracing.accounted("msg_recv")
     async def recv(self, session_key: bytes | None) -> Frame:
         await self._maybe_inject(yield_loop=False)
         if self._loaned:

@@ -28,6 +28,7 @@ from __future__ import annotations
 import asyncio
 import socket as socket_mod
 
+from ceph_tpu.common import tracer as tracing
 from ceph_tpu.lint import racecheck
 from ceph_tpu.msg.frames import Frame, read_frame
 
@@ -52,6 +53,7 @@ class PosixStack(NetworkStack):
 
     scheme = "tcp"
 
+    @tracing.accounted("msg_connect")
     async def connect(self, addr):
         host, port = addr
         return await asyncio.open_connection(host, port)
@@ -70,6 +72,7 @@ class LocalStack(NetworkStack):
 
     scheme = "uds"
 
+    @tracing.accounted("msg_connect")
     async def connect(self, addr):
         return await asyncio.open_unix_connection(addr)
 
@@ -197,6 +200,7 @@ class InjectingStream:
     async def send(self, frame: Frame, session_key: bytes | None) -> None:
         await self.send_frames([frame], session_key)
 
+    @tracing.accounted("msg_send")
     async def send_frames(
         self, frames: list, session_key: bytes | None, coalesced: int = 1
     ) -> None:
@@ -227,6 +231,7 @@ class InjectingStream:
         racecheck.note_io("msg.send")
         await self.writer.drain()
 
+    @tracing.accounted("msg_recv")
     async def recv(self, session_key: bytes | None) -> Frame:
         await self._maybe_inject(yield_loop=False)
         return await read_frame(self.reader, session_key)

@@ -741,6 +741,10 @@ class OSDService(Dispatcher):
         """Store utilization (ObjectStore::statfs): advertised capacity
         comes from config (the disk-size role), used bytes from the live
         KV footprint. Cached briefly — the scan is O(rows)."""
+        with tracing.account("store"):
+            return self._statfs()
+
+    def _statfs(self) -> dict:
         loop = asyncio.get_event_loop()
         cached = getattr(self, "_statfs_cache", None)
         ttl = float(self.config.get("osd_statfs_cache_sec"))
@@ -1084,40 +1088,41 @@ class OSDService(Dispatcher):
         frame when several coalesced, the plain per-op message when one.
         A send failure here is absorbed — every queued op has a waiter
         with a deadline, and _sub_op_persist retries on timeout."""
-        pend = self._subop_pending.pop(osd, None)
-        if not pend:
-            return
-        try:
-            conn = self._osd_conn(osd)
-            if len(pend) == 1:
-                mtype, payload, raw, wire = pend[0]
-                self.perf.inc("subop_direct")
-                conn.send_message(
-                    Message(type=mtype, tid=payload["tid"],
-                            epoch=self.osdmap.epoch,
-                            payload=payload, raw=raw, trace=wire)
-                )
+        with tracing.account("osd"):
+            pend = self._subop_pending.pop(osd, None)
+            if not pend:
                 return
-            ops = [
-                {"type": mtype, "payload": payload,
-                 "raw_len": len(raw), "trace": wire}
-                for mtype, payload, raw, wire in pend
-            ]
-            btid = next(self._tids)
-            # the frame carries every inner op's context, so its
-            # messenger legs land on each op's trace
-            conn.send_message(
-                Message(type="subop_batch", tid=btid,
-                        epoch=self.osdmap.epoch,
-                        payload={"tid": btid, "ops": ops},
-                        raw=b"".join(raw for _, _, raw, _ in pend),
-                        trace=",".join(w for *_, w in pend if w))
-            )
-            self.perf.inc("subop_batch_tx")
-            self.perf.inc("subop_batch_tx_ops", len(pend))
-        # cephlint: disable=error-taxonomy (waiters time out; _sub_op_persist re-targets/retries)
-        except Exception:
-            pass  # waiters time out; _sub_op_persist re-targets/retries
+            try:
+                conn = self._osd_conn(osd)
+                if len(pend) == 1:
+                    mtype, payload, raw, wire = pend[0]
+                    self.perf.inc("subop_direct")
+                    conn.send_message(
+                        Message(type=mtype, tid=payload["tid"],
+                                epoch=self.osdmap.epoch,
+                                payload=payload, raw=raw, trace=wire)
+                    )
+                    return
+                ops = [
+                    {"type": mtype, "payload": payload,
+                     "raw_len": len(raw), "trace": wire}
+                    for mtype, payload, raw, wire in pend
+                ]
+                btid = next(self._tids)
+                # the frame carries every inner op's context, so its
+                # messenger legs land on each op's trace
+                conn.send_message(
+                    Message(type="subop_batch", tid=btid,
+                            epoch=self.osdmap.epoch,
+                            payload={"tid": btid, "ops": ops},
+                            raw=b"".join(raw for _, _, raw, _ in pend),
+                            trace=",".join(w for *_, w in pend if w))
+                )
+                self.perf.inc("subop_batch_tx")
+                self.perf.inc("subop_batch_tx_ops", len(pend))
+            # cephlint: disable=error-taxonomy (waiters time out; _sub_op_persist re-targets/retries)
+            except Exception:
+                pass  # waiters time out; _sub_op_persist re-targets/retries
 
     def _reply_peer(
         self, conn, p: dict, payload: dict, raw: bytes = b""
@@ -1135,6 +1140,7 @@ class OSDService(Dispatcher):
 
     # -- dispatch -------------------------------------------------------------
 
+    @tracing.accounted("osd")
     async def ms_dispatch(self, conn, msg: Message) -> None:
         p = payload_of(msg)
         p["_raw"] = msg.raw  # the bulk data segment, bytes verbatim
@@ -1191,6 +1197,7 @@ class OSDService(Dispatcher):
                 self._subop_batch_ack(conn, p.get("tid", 0), cols)
             )
 
+    @tracing.accounted("osd")
     async def _subop_batch_ack(self, conn, btid: int, cols) -> None:
         """One coalesced sub_reply for every inner op that acked within
         the window; each op acks/fails INDEPENDENTLY — a straggler is
@@ -1286,6 +1293,7 @@ class OSDService(Dispatcher):
         grace = self.config.get("osd_heartbeat_grace")
         loop = asyncio.get_event_loop()
 
+        @tracing.accounted("osd_heartbeat")
         async def ping(peer: int) -> None:
             try:
                 await self._peer_call(peer, "osd_ping", {}, timeout=grace)
@@ -2958,10 +2966,12 @@ class OSDService(Dispatcher):
     async def _h_ec_sub_write(self, conn, p) -> None:
         self._enqueue_subop(p, self._do_ec_sub_write, conn)
 
+    @tracing.accounted("osd")
     async def _do_ec_sub_write(self, conn, p) -> None:
         with self.perf.time("l_subop_apply"):
             await self._do_ec_sub_write_inner(conn, p)
 
+    @tracing.accounted("osd")
     async def _do_ec_sub_write_inner(self, conn, p) -> None:
         """ECBackend::handle_sub_write for our shard."""
         pg = self._pg_of(p["pgid"])
@@ -3363,16 +3373,18 @@ class OSDService(Dispatcher):
     async def _subop_worker(self, pg: PG) -> None:
         while not self._stopped:
             fn, conn, p = await pg.subop_q.get()
-            if "_queued_at" in p:
-                self.perf.tinc(
-                    "l_subop_queue", time.time() - p["_queued_at"]
-                )
-            qs = p.pop("_qspan", None)
-            if qs is not None:
-                qs.finish()
-            # sub-op handlers run under the SENDER's fork span, so the
-            # shard-side journal/store spans attach to the right branch
-            stoken = self.tracer.use_wire(p.get("_trace"))
+            with tracing.account("osd"):
+                if "_queued_at" in p:
+                    self.perf.tinc(
+                        "l_subop_queue", time.time() - p["_queued_at"]
+                    )
+                qs = p.pop("_qspan", None)
+                if qs is not None:
+                    qs.finish()
+                # sub-op handlers run under the SENDER's fork span, so
+                # the shard-side journal/store spans attach to the right
+                # branch
+                stoken = self.tracer.use_wire(p.get("_trace"))
             try:
                 await fn(conn, p)
             except asyncio.CancelledError:
@@ -3432,7 +3444,8 @@ class OSDService(Dispatcher):
 
     async def _op_shard_worker(self, shard) -> None:
         while not self._stopped:
-            item = shard.queue.dequeue()
+            with tracing.account("osd"):
+                item = shard.queue.dequeue()
             if item is None:
                 if len(shard.queue):
                     # mclock limit throttling: ops exist but none are
@@ -3456,7 +3469,9 @@ class OSDService(Dispatcher):
                 continue
             name = p.get("name")
             inflight = shard.inflight.get(name)
-            if self._op_pipelines(p):
+            with tracing.account("osd"):
+                pipelined = self._op_pipelines(p)
+            if pipelined:
                 # EC all-write vectors run as their own tasks so the
                 # sub-stripe RMW read+encode legs of in-flight writes
                 # overlap (ECBackend pipelines rmw ops the same way,
@@ -3505,6 +3520,7 @@ class OSDService(Dispatcher):
         ops = p.get("ops") or []
         return bool(ops) and all(o.get("op") == "write" for o in ops)
 
+    @tracing.accounted("osd")
     async def _run_client_op(self, conn, p) -> None:
         pool_id = p["pool"]
         name = p["name"]
@@ -3535,6 +3551,7 @@ class OSDService(Dispatcher):
                 span.finish()
                 self.tracer.release(stoken)
 
+    @tracing.accounted("osd")
     async def _do_osd_op(self, conn, p, pool_id, name, tracked) -> None:
         try:
             if pool_id not in self.osdmap.pools:
@@ -3803,6 +3820,7 @@ class OSDService(Dispatcher):
                 f"members, below min_size {pool.min_size}"
             )
 
+    @tracing.accounted("osd")
     async def _sub_op_persist(
         self, pg: PG, osd: int, mtype: str, payload: dict, raw: bytes = b""
     ) -> None:
@@ -3886,6 +3904,7 @@ class OSDService(Dispatcher):
             txn.omap_setkeys(pg.coll, name, state.omap_sets)
         return txn
 
+    @tracing.accounted("osd")
     async def _primary_ops(
         self, pg: PG, acting: list[int], name: str, ops: list[dict],
         datas: list[bytes], reqid: str | None,
@@ -4230,6 +4249,7 @@ class OSDService(Dispatcher):
             state.xattrs = self._head_xattrs(pg, acting, name)
         return state
 
+    @tracing.accounted("osd")
     async def _fan_ec_write(
         self, pg: PG, acting: list[int], name: str, data: bytes,
         entry: dict, xattrs: dict[str, bytes] | None = None,
@@ -4722,6 +4742,22 @@ class OSDService(Dispatcher):
             if sp is not None:
                 sp.finish()
 
+    @tracing.accounted("osd")
+    async def _fetch_shard(
+        self, pg: PG, name: str, entry: dict, s: int, osd: int
+    ) -> tuple[int, dict]:
+        """Shard `s` of the object's current version from `osd`, for a
+        primary read: (s, reply), or (s, {"ok": False}) on a miss."""
+        try:
+            return s, await self._peer_call(
+                osd, "obj_read",
+                {"coll": pg.coll, "name": shard_name(name, s),
+                 "ver": entry["obj_ver"]},
+                timeout=2.0, batchable=True,
+            )
+        except (asyncio.TimeoutError, RuntimeError):
+            return s, {"ok": False}
+
     async def _primary_read(
         self, pg: PG, acting: list[int], name: str
     ) -> bytes:
@@ -4794,17 +4830,6 @@ class OSDService(Dispatcher):
                 available[pos] = osd
         want = {ec.chunk_index(i)
                 for i in range(ec.get_data_chunk_count())}
-        async def _fetch_shard(s: int) -> tuple[int, dict]:
-            try:
-                return s, await self._peer_call(
-                    available[s], "obj_read",
-                    {"coll": pg.coll, "name": shard_name(name, s),
-                     "ver": entry["obj_ver"]},
-                    timeout=2.0, batchable=True,
-                )
-            except (asyncio.TimeoutError, RuntimeError):
-                return s, {"ok": False}
-
         while True:
             minimum = ec.minimum_to_decode(want, set(available))
             fetch = [s for s in minimum if s not in chunks]
@@ -4813,7 +4838,8 @@ class OSDService(Dispatcher):
             # k serial round trips, and same-tick fetches to one peer ride
             # a single batched sub-op frame
             results = await asyncio.gather(
-                *(_fetch_shard(s) for s in fetch)
+                *(self._fetch_shard(pg, name, entry, s, available[s])
+                  for s in fetch)
             )
             for s, rep in results:
                 if not rep.get("ok"):

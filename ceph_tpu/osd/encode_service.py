@@ -49,12 +49,16 @@ def _device_leg(sp, launch):
     host memory. On a traced batch, tag the span with the seconds of
     packing before it (pack_s), when it began (device_start, on the
     tracer's clock) and how long it took (device_s); an untraced batch
-    takes no timestamp."""
-    if sp is None:
-        return launch()
-    t0 = tracing.now()
-    out = launch()
-    t1 = tracing.now()
+    takes no timestamp. The loop's CPU in it is the account ec_device;
+    its wall seconds less its CPU bound the wait on the device and the
+    transfers from above (they also hold waits for the interpreter lock
+    and the loop thread's preemption)."""
+    with tracing.account("ec_device"):
+        if sp is None:
+            return launch()
+        t0 = tracing.now()
+        out = launch()
+        t1 = tracing.now()
     sp.set_tag("pack_s", t0 - sp.start)
     sp.set_tag("device_start", t0)
     sp.set_tag("device_s", t1 - t0)
@@ -202,7 +206,10 @@ class EncodeService:
             "encode_batch", tags={"batch": len(q)}
         )
         try:
-            self._flush_encode_inner(key, q, codec, k, n, sp)
+            # a launch's CPU is three accounts: ec_pack (plan and pack,
+            # and whatever the other two leave), ec_device, ec_unpack
+            with tracing.account("ec_pack"):
+                self._flush_encode_inner(key, q, codec, k, n, sp)
         finally:
             if sp is not None:
                 sp.finish()
@@ -221,14 +228,15 @@ class EncodeService:
                     codec, planes, mesh))
             self.launches += 1
             self.objects += len(q)
-            for rows, out, (_obj, _bs, fut) in zip(data, parity, q):
-                chunks = {codec.chunk_index(i): rows[i].tobytes()
-                          for i in range(k)}
-                for logical, row in zip(plan.targets, out):
-                    chunks[codec.chunk_index(logical)] = row.tobytes()
-                if not fut.done():
-                    fut.set_result(chunks)
-            _tag_unpacked(sp)
+            with tracing.account("ec_unpack"):
+                for rows, out, (_obj, _bs, fut) in zip(data, parity, q):
+                    chunks = {codec.chunk_index(i): rows[i].tobytes()
+                              for i in range(k)}
+                    for logical, row in zip(plan.targets, out):
+                        chunks[codec.chunk_index(logical)] = row.tobytes()
+                    if not fut.done():
+                        fut.set_result(chunks)
+                _tag_unpacked(sp)
         except Exception as e:
             for _obj, _bs, fut in q:
                 if not fut.done():
@@ -283,7 +291,8 @@ class EncodeService:
         out = _device_leg(sp, launch)
         if mesh is not None:
             self.mesh_launches += 1
-        return codec.unpack_planes(out, widths)
+        with tracing.account("ec_unpack"):
+            return codec.unpack_planes(out, widths)
 
     # -- decode ---------------------------------------------------------------
 
@@ -359,7 +368,8 @@ class EncodeService:
             tags={"batch": len(q), "targets": len(targets)},
         )
         try:
-            self._flush_decode_inner(key, q, codec, sp)
+            with tracing.account("ec_pack"):
+                self._flush_decode_inner(key, q, codec, sp)
         finally:
             if sp is not None:
                 sp.finish()
@@ -376,15 +386,16 @@ class EncodeService:
                     codec, list(plan.inputs), list(targets), planes, mesh))
             self.launches += 1
             self.objects += len(q)
-            for (chunks, _bs, want, fut), out in zip(q, rebuilt):
-                res = {i: bytes(chunks[i]) for i in want if i in chunks}
-                for logical, row in zip(targets, out):
-                    phys = codec.chunk_index(logical)
-                    if phys in want:
-                        res[phys] = row.tobytes()
-                if not fut.done():
-                    fut.set_result(res)
-            _tag_unpacked(sp)
+            with tracing.account("ec_unpack"):
+                for (chunks, _bs, want, fut), out in zip(q, rebuilt):
+                    res = {i: bytes(chunks[i]) for i in want if i in chunks}
+                    for logical, row in zip(targets, out):
+                        phys = codec.chunk_index(logical)
+                        if phys in want:
+                            res[phys] = row.tobytes()
+                    if not fut.done():
+                        fut.set_result(res)
+                _tag_unpacked(sp)
         except Exception as e:
             for _c, _b, _w, fut in q:
                 if not fut.done():

@@ -37,6 +37,7 @@ iteration yields collection listings.
 
 from __future__ import annotations
 
+from ceph_tpu.common import tracer as tracing
 from ceph_tpu.common.encoding import Decoder, Encoder
 from ceph_tpu.common.kv import KeyValueDB, KVTransaction, MemDB
 
@@ -223,15 +224,16 @@ class KStore:
             tags={"store": self.KIND, "ops": len(txn.ops)},
         )
         try:
-            kv = KVTransaction()
-            self._begin_batch()
-            try:
-                for op in txn.ops:
-                    self._compile_op(kv, op)
-            except BaseException:
-                self._abort_batch()
-                raise
-            self._commit_batch(kv)
+            with tracing.account("store"):
+                kv = KVTransaction()
+                self._begin_batch()
+                try:
+                    for op in txn.ops:
+                        self._compile_op(kv, op)
+                except BaseException:
+                    self._abort_batch()
+                    raise
+                self._commit_batch(kv)
         finally:
             if sp is not None:
                 sp.finish()

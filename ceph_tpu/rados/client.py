@@ -18,6 +18,7 @@ import itertools
 import json
 import os
 
+from ceph_tpu.common import tracer as tracing
 from ceph_tpu.common.config import Config
 from ceph_tpu.common.hash import ceph_str_hash_rjenkins
 from ceph_tpu.common.watchdog import SharedWatchdog
@@ -164,6 +165,7 @@ class Objecter(Dispatcher):
     #: messenger/monclient instead of running their own transport
     ext_dispatch = None
 
+    @tracing.accounted("client")
     async def ms_dispatch(self, conn, msg: Message) -> None:
         if self.ext_dispatch is not None and msg.type.startswith(
             "mds_"
@@ -357,6 +359,7 @@ class Objecter(Dispatcher):
 
     # -- op submission --------------------------------------------------------
 
+    @tracing.accounted("client")
     async def op_submit(
         self,
         pool_id: int,
@@ -470,6 +473,7 @@ class Objecter(Dispatcher):
             return True
         return op == "ops" and not is_mutating(ex.get("ops") or ())
 
+    @tracing.accounted("client")
     async def _op_submit_inner(
         self, pool_id, name, op, data, deadline, last_error, tid,
         span, wire_ctx, extra, read_policy=None,
